@@ -32,7 +32,9 @@ Prints ``name,us_per_call,derived`` CSV.  Modules:
                        BENCH_fleet.json)
 """
 import argparse
+import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -67,15 +69,21 @@ def main() -> None:
     picked = (args.only.split(",") if args.only else list(mods))
     print("name,us_per_call,derived")
     rows = []
+    failed = []
     for name in picked:
         t0 = time.time()
         print(f"# --- {name} ---", flush=True)
         try:
             mods[name].main(rows)
-        except Exception as e:  # pragma: no cover - keep harness robust
+        except Exception as e:
+            # run the remaining benchmarks, then fail the harness
+            traceback.print_exc()
             print(f"{name},0,ERROR:{e!r}", flush=True)
+            failed.append(name)
         print(f"# {name} took {time.time()-t0:.1f}s", flush=True)
     print(f"# total rows: {len(rows)}")
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -20,6 +20,7 @@ from repro.checkpointing import save_checkpoint
 from repro.configs import get_arch
 from repro.configs.base import FedConfig, RunConfig
 from repro.data.synthetic import make_token_dataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.train import init_state, make_train_step
 
 
@@ -32,6 +33,7 @@ def main():
                     help="~100M-param variant (slow on CPU; the dry-run "
                          "exercises the full-size configs)")
     args = ap.parse_args()
+    use_compile_cache()
 
     base = get_arch(args.arch).reduced()
     if args.full:   # ~100M params

@@ -70,7 +70,7 @@ def _sgd_step(theta, g, eta, fed):
     g = _wd(theta, _maybe_clip(g, fed), fed)
     if fed.use_pallas:
         from repro.kernels import ops
-        return jax.tree.map(lambda t, gi: ops.fused_axpy(t, gi, -eta), theta, g)
+        return ops.tree_fused_axpy(theta, g, -eta)
     return jax.tree.map(lambda t, gi: t - eta * gi, theta, g)
 
 

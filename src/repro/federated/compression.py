@@ -145,14 +145,15 @@ class TopKCompressor(Compressor):
         # flatten/unflatten rather than unzipping an is_leaf-on-tuples map:
         # the input pytree may contain tuple internal nodes a tuple
         # heuristic would mistake for (q, residual) pairs
-        leaves, treedef = jax.tree.flatten(v)
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(v)
         pairs = []
-        for x in leaves:
+        for path, x in leaves:
             flat = jnp.abs(x.reshape(-1))
             thresh = jax.lax.top_k(flat, self._k(flat.size))[0][-1]
             if self.use_pallas:
                 from repro.kernels import ops
-                pairs.append(ops.topk_compress_leaf(x, thresh))
+                pairs.append(ops.topk_compress_leaf(
+                    x, thresh, ops.leaf_spec(path, x.shape)))
             else:
                 pairs.append(ref.topk_threshold_select(x, thresh))
         return (jax.tree.unflatten(treedef, [p[0] for p in pairs]),
@@ -180,15 +181,16 @@ class QSGDCompressor(Compressor):
 
     def compress(self, delta, ef, key):
         v = T.add(delta, ef)
-        leaves, treedef = jax.tree.flatten(v)
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(v)
         keys = jax.random.split(key, len(leaves))
         pairs = []
-        for x, k in zip(leaves, keys):
+        for (path, x), k in zip(leaves, keys):
             u = jax.random.uniform(k, x.shape, dtype=x.dtype)
             scale = jnp.max(jnp.abs(x))
             if self.use_pallas:
                 from repro.kernels import ops
-                pairs.append(ops.qsgd_compress_leaf(x, u, scale, self.levels))
+                pairs.append(ops.qsgd_compress_leaf(
+                    x, u, scale, self.levels, ops.leaf_spec(path, x.shape)))
             else:
                 pairs.append(ref.qsgd_quantize(x, u, scale, self.levels))
         return (jax.tree.unflatten(treedef, [p[0] for p in pairs]),

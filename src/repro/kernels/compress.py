@@ -19,7 +19,12 @@ no intermediates.
 
 Tiling mirrors fedadc_update.py: flattened (rows, 128) lane-aligned tiles
 (padding handled by the ops.py wrapper); per-leaf scalars (scale, threshold)
-are broadcast along lanes like the weights in weighted_reduce.py.
+are broadcast along lanes like the weights in weighted_reduce.py.  They
+travel in fp32 whatever the operand dtype — Mosaic extracts only 32-bit
+scalars from a vector — and are read as (1, LANE) rows, not scalars.  A
+scalar of the operand dtype is exact in fp32, so the threshold compare
+stays exact.  The quantiser computes in fp32 (the v5e has no bf16 vector
+compare), rounding each step to the operand dtype.
 """
 from __future__ import annotations
 
@@ -35,21 +40,29 @@ BLOCK_ROWS = 512          # 512×128 fp32 = 256 KiB per operand in VMEM
 
 def _qsgd_kernel(v_ref, u_ref, scale_ref, q_ref, r_ref, *, s):
     # y = |v|·s/scale ; level = ⌊y⌋ + 1[u < frac(y)] ; q = sign(v)·level·scale/s
-    v = v_ref[...]
-    scale = scale_ref[0, 0]
-    inv = jnp.where(scale > 0, s / jnp.maximum(scale, 1e-30), 0.0)
-    y = jnp.abs(v) * inv
+    # Each step runs in fp32 (the chip has no bf16 vector compare) and is
+    # rounded to the operand dtype, as the oracle's in-dtype arithmetic
+    # rounds it — so the level decisions match the oracle's.
+    dt = v_ref.dtype
+
+    def rd(x):
+        return x.astype(dt).astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    u = u_ref[...].astype(jnp.float32)
+    scale = scale_ref[...]                          # (1, LANE) fp32, exact
+    inv = rd(jnp.where(scale > 0, rd(s / jnp.maximum(scale, 1e-30)), 0.0))
+    y = rd(jnp.abs(v) * inv)
     lower = jnp.floor(y)
-    level = lower + (u_ref[...] < (y - lower)).astype(v.dtype)
-    q = jnp.sign(v) * level * (scale / s)
-    q_ref[...] = q
-    r_ref[...] = v - q
+    level = rd(lower + (u < rd(y - lower)).astype(jnp.float32))
+    q = rd(rd(jnp.sign(v) * level) * rd(scale / s))
+    q_ref[...] = q.astype(dt)
+    r_ref[...] = (v - q).astype(dt)
 
 
 def _threshold_kernel(v_ref, t_ref, q_ref, r_ref):
     # q = v·1[|v| ≥ τ] ; r = v − q   (τ = per-leaf k-th largest magnitude)
     v = v_ref[...]
-    keep = jnp.abs(v) >= t_ref[0, 0]
+    keep = jnp.abs(v).astype(jnp.float32) >= t_ref[...]
     q = jnp.where(keep, v, jnp.zeros_like(v))
     q_ref[...] = q
     r_ref[...] = v - q
@@ -64,7 +77,8 @@ def _tiled_call(kernel, arrays, scalars, interpret, **kw):
     grid = (pl.cdiv(rows, block),)
     spec = pl.BlockSpec((block, LANE), lambda i: (i, 0))
     sspec = pl.BlockSpec((1, LANE), lambda i: (0, 0))
-    s2d = [jnp.broadcast_to(jnp.asarray(s, dtype).reshape(1, 1), (1, LANE))
+    s2d = [jnp.broadcast_to(jnp.asarray(s, dtype).astype(jnp.float32)
+                            .reshape(1, 1), (1, LANE))
            for s in scalars]
     out_shape = [jax.ShapeDtypeStruct(arrays[0].shape, dtype)] * 2
     return pl.pallas_call(

@@ -3,6 +3,14 @@
 On non-TPU backends every kernel runs in ``interpret=True`` mode (the body
 executes as plain JAX on CPU) so the whole framework stays runnable and
 testable in this container; on TPU the same call sites compile to Mosaic.
+
+A Mosaic kernel cannot be partitioned by the compiler.  Under a
+multi-device mesh declared with ``jax.set_mesh``, each wrapper therefore
+runs its kernel in ``shard_map``: every device applies the kernel to its
+own shard.  Per-element kernels over parameter trees take each leaf's
+own layout from ``sharding.specs`` (no gather); flash attention keeps its
+batch and head sharding and gathers the sequence.  Without such a mesh
+(one device, or the vmapped simulator rounds) the kernel is called as is.
 """
 from __future__ import annotations
 
@@ -11,11 +19,13 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import compress as _cp
 from repro.kernels import fedadc_update as _fu
 from repro.kernels import flash_attention as _fa
 from repro.kernels import kd_loss as _kd
+from repro.kernels import ref as _ref
 from repro.kernels import sparse_reduce as _sr
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import weighted_reduce as _wr
@@ -25,6 +35,31 @@ LANE = _fu.LANE
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _mesh():
+    """The multi-device mesh declared with ``jax.set_mesh``, else None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty or mesh.size == 1 else mesh
+
+
+def _on_shards(fn, in_specs, out_specs, *args):
+    """``fn(*args)``, run by each device on its own shards under a declared
+    multi-device mesh (the partitioner reshards operands to `in_specs`)."""
+    if _mesh() is None:
+        return fn(*args)
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)(*args)
+
+
+def leaf_spec(path, shape):
+    """The partition spec the pod engine gives a parameter-shaped leaf (a
+    stacked leaf passes its per-client shape)."""
+    mesh = _mesh()
+    if mesh is None:
+        return P()
+    from repro.sharding.specs import spec_for_param  # lazy: layering
+    return spec_for_param(path, shape, mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -45,36 +80,56 @@ def _from_tiles(t, pad, shape, dtype):
     return flat.reshape(shape).astype(dtype)
 
 
-def fused_axpy(x, y, a):
-    """x + a·y on a single leaf."""
-    xt, pad = _as_tiles(x)
-    yt, _ = _as_tiles(y.astype(x.dtype))
-    out = _fu.fused_axpy_2d(xt, yt, a, interpret=_interpret())
-    return _from_tiles(out, pad, x.shape, x.dtype)
+def fused_axpy(x, y, a, spec=P()):
+    """x + a·y on a single leaf laid out as `spec` under a mesh."""
+    def leaf(x, y):
+        xt, pad = _as_tiles(x)
+        yt, _ = _as_tiles(y)
+        out = _fu.fused_axpy_2d(xt, yt, a, interpret=_interpret())
+        return _from_tiles(out, pad, x.shape, x.dtype)
+    return _on_shards(leaf, (spec, spec), spec, x, y.astype(x.dtype))
+
+
+def tree_fused_axpy(xs, ys, a):
+    """x + a·y over a parameter-shaped pytree, each leaf in its own
+    layout."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x, y: fused_axpy(x, y, a, leaf_spec(path, x.shape)),
+        xs, ys)
 
 
 def fedadc_local_update(theta, g, m_bar, eta):
     """θ − η(g + m̄) over a whole pytree."""
     def leaf(t, gi, mi):
         tt, pad = _as_tiles(t)
-        gt, _ = _as_tiles(gi.astype(t.dtype))
-        mt, _ = _as_tiles(mi.astype(t.dtype))
+        gt, _ = _as_tiles(gi)
+        mt, _ = _as_tiles(mi)
         out = _fu.local_update_2d(tt, gt, mt, eta, interpret=_interpret())
         return _from_tiles(out, pad, t.shape, t.dtype)
-    return jax.tree.map(leaf, theta, g, m_bar)
+
+    def apply(path, t, gi, mi):
+        spec = leaf_spec(path, t.shape)
+        return _on_shards(leaf, (spec,) * 3, spec, t, gi.astype(t.dtype),
+                          mi.astype(t.dtype))
+    return jax.tree_util.tree_map_with_path(apply, theta, g, m_bar)
 
 
 def fedadc_server_update(theta, m, delta_bar, gamma, alpha_eta):
     """(θ', m') fused server update over a whole pytree."""
     def leaf(t, mi, di):
         tt, pad = _as_tiles(t)
-        mt, _ = _as_tiles(mi.astype(t.dtype))
-        dt, _ = _as_tiles(di.astype(t.dtype))
+        mt, _ = _as_tiles(mi)
+        dt, _ = _as_tiles(di)
         to, mo = _fu.server_update_2d(tt, mt, dt, gamma, alpha_eta,
                                       interpret=_interpret())
         return (_from_tiles(to, pad, t.shape, t.dtype),
                 _from_tiles(mo, pad, t.shape, t.dtype))
-    pairs = jax.tree.map(leaf, theta, m, delta_bar)
+
+    def apply(path, t, mi, di):
+        spec = leaf_spec(path, t.shape)
+        return _on_shards(leaf, (spec,) * 3, (spec, spec), t,
+                          mi.astype(t.dtype), di.astype(t.dtype))
+    pairs = jax.tree_util.tree_map_with_path(apply, theta, m, delta_bar)
     theta_new = jax.tree.map(lambda p: p[0], pairs,
                              is_leaf=lambda x: isinstance(x, tuple))
     m_new = jax.tree.map(lambda p: p[1], pairs,
@@ -85,39 +140,50 @@ def fedadc_server_update(theta, m, delta_bar, gamma, alpha_eta):
 def weighted_delta_reduce(stacked, weights):
     """Σ_k w_k·Δ_k over a stacked pytree (leading axis K on every leaf).
     Weights are applied as given (normalise upstream for a weighted mean)."""
-    def leaf(d):
+    def leaf(d, w):
         k = d.shape[0]
         flat = d.reshape(k, -1)
         pad = (-flat.shape[1]) % LANE
         if pad:
             flat = jnp.pad(flat, ((0, 0), (0, pad)))
         tiles = flat.reshape(k, -1, LANE)
-        out = _wr.weighted_reduce_2d(tiles, weights, interpret=_interpret())
+        out = _wr.weighted_reduce_2d(tiles, w, interpret=_interpret())
         return _from_tiles(out, pad, d.shape[1:], d.dtype)
-    return jax.tree.map(leaf, stacked)
+
+    def reduce(path, d):
+        # the reduced K axis stays whole on every device
+        spec = leaf_spec(path, d.shape[1:])
+        return _on_shards(leaf, (P(None, *spec), P(None)), spec, d, weights)
+    return jax.tree_util.tree_map_with_path(reduce, stacked)
 
 
 # ---------------------------------------------------------------------------
 # delta compression — single-leaf quantise/sparsify round trips
 # ---------------------------------------------------------------------------
-def qsgd_compress_leaf(v, u, scale, s):
-    """Stochastic uniform quantise-dequantise on one leaf.  `u` uniform draw
-    (v's shape), `scale` per-leaf scalar, `s` static level count.
+def qsgd_compress_leaf(v, u, scale, s, spec=P()):
+    """Stochastic uniform quantise-dequantise on one leaf laid out as
+    `spec` under a mesh.  `u` uniform draw (v's shape), `scale` per-leaf
+    scalar, `s` static level count.
     -> (dequantised q, residual v − q), both v's shape/dtype."""
-    vt, pad = _as_tiles(v)
-    ut, _ = _as_tiles(u.astype(v.dtype))
-    q, r = _cp.qsgd_2d(vt, ut, scale, s, interpret=_interpret())
-    return (_from_tiles(q, pad, v.shape, v.dtype),
-            _from_tiles(r, pad, v.shape, v.dtype))
+    def leaf(v, u, scale):
+        vt, pad = _as_tiles(v)
+        ut, _ = _as_tiles(u)
+        q, r = _cp.qsgd_2d(vt, ut, scale, s, interpret=_interpret())
+        return (_from_tiles(q, pad, v.shape, v.dtype),
+                _from_tiles(r, pad, v.shape, v.dtype))
+    return _on_shards(leaf, (spec, spec, P()), (spec, spec), v,
+                      u.astype(v.dtype), scale)
 
 
-def topk_compress_leaf(v, thresh):
-    """Magnitude-threshold select on one leaf (top-k with τ precomputed).
-    -> (selected q, residual v − q)."""
-    vt, pad = _as_tiles(v)
-    q, r = _cp.threshold_select_2d(vt, thresh, interpret=_interpret())
-    return (_from_tiles(q, pad, v.shape, v.dtype),
-            _from_tiles(r, pad, v.shape, v.dtype))
+def topk_compress_leaf(v, thresh, spec=P()):
+    """Magnitude-threshold select on one leaf laid out as `spec` under a
+    mesh (top-k with τ precomputed).  -> (selected q, residual v − q)."""
+    def leaf(v, thresh):
+        vt, pad = _as_tiles(v)
+        q, r = _cp.threshold_select_2d(vt, thresh, interpret=_interpret())
+        return (_from_tiles(q, pad, v.shape, v.dtype),
+                _from_tiles(r, pad, v.shape, v.dtype))
+    return _on_shards(leaf, (spec, P()), (spec, spec), v, thresh)
 
 
 def topk_sparse_leaf(v, k):
@@ -183,15 +249,50 @@ def sparse_weighted_delta_reduce(values, indices, weights, shape, dtype):
 # ---------------------------------------------------------------------------
 # attention / ssd / kd
 # ---------------------------------------------------------------------------
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=True, window=0, block_q=128, block_k=128):
-    """q (B,L,H,D) model layout -> (B,L,H,D)."""
-    qt = jnp.moveaxis(q, 1, 2)
-    kt = jnp.moveaxis(k, 1, 2)
-    vt = jnp.moveaxis(v, 1, 2)
-    out = _fa.flash_attention(qt, kt, vt, causal=causal, window=window,
-                              block_q=block_q, block_k=block_k,
-                              interpret=_interpret())
-    return jnp.moveaxis(out, 1, 2)
+    """q (B,L,H,D) model layout -> (B,L,H,D).
+
+    Differentiable: the forward pass is the Pallas kernel, the backward
+    pass is the VJP of the float32 oracle (``ref.flash_attention``),
+    recomputed from (q, k, v) — so a training step through this op keeps
+    no (L, L) residual between its passes."""
+    def kernel(q, k, v):
+        out = _fa.flash_attention(
+            jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2),
+            jnp.moveaxis(v, 1, 2), causal=causal, window=window,
+            block_q=block_q, block_k=block_k, interpret=_interpret())
+        return jnp.moveaxis(out, 1, 2)
+
+    spec = P()
+    mesh = _mesh()
+    if mesh is not None:
+        # batch over "data", heads over "model" where they divide; q and kv
+        # heads split alike, so each shard keeps whole GQA groups
+        def axis(name, dim):
+            n = mesh.shape.get(name, 1)
+            return name if n > 1 and dim % n == 0 else None
+        spec = P(axis("data", q.shape[0]), None, axis("model", k.shape[2]),
+                 None)
+    return _on_shards(kernel, (spec,) * 3, spec, q, k, v)
+
+
+def _flash_attention_fwd(q, k, v, causal, window, block_q, block_k):
+    return flash_attention(q, k, v, causal, window, block_q, block_k), \
+        (q, k, v)
+
+
+def _flash_attention_bwd(causal, window, block_q, block_k, res, g):
+    def oracle(q, k, v):
+        out = _ref.flash_attention(jnp.moveaxis(q, 1, 2),
+                                   jnp.moveaxis(k, 1, 2),
+                                   jnp.moveaxis(v, 1, 2),
+                                   causal=causal, window=window)
+        return jnp.moveaxis(out, 1, 2)
+    return jax.vjp(oracle, *res)[1](g)
+
+
+flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
 def ssd_scan(x, dt, A_log, B, C, D, chunk=256):

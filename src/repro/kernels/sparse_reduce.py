@@ -7,46 +7,56 @@ aggregation FLOPs and memory traffic scaled with the parameter count d
 even at ``topk_frac = 0.01``.  This kernel segment-sums the K stacked
 wire pairs straight into one dense output leaf:
 
-    out[idx_{k,j}] += w_k · values_{k,j}      (K · k adds, not K · d)
+    out[idx_{k,j}] += w_k · values_{k,j}      (K · k adds per block)
 
-Tiling: one grid step per client; the output block (the whole padded
-leaf, lane-aligned) is *revisited* across the K steps — a constant
-output index map keeps the running sum resident, and the fp32 output ref
-IS the accumulator, so accumulation is fp32 whatever the wire dtype
-(PR 5's cast-on-write precision contract; the ops.py wrapper casts to
-the wire dtype exactly once, on the final write).  Duplicate indices
-within a client accumulate (scatter-add), matching the segment-sum
-oracle in kernels/ref.py bit for bit: both apply the weighted updates in
-client-major order onto an fp32 zero buffer.
+Tiling: the output is cut into row blocks of ``BLOCK_ROWS`` × 128 and
+the grid is (row block, client, pair chunk) with the client and chunk axes
+innermost, so each output block stays resident while every client's
+pairs stream past it.  The fp32 output ref IS the accumulator, so
+accumulation is fp32 whatever the wire dtype (the cast-on-write precision
+contract: the ops.py wrapper casts to the wire dtype exactly once, on the
+final write).  Each step adds the pairs whose index falls in its block;
+the others add an exact +0.0 at the block's first element.  Per output
+element the adds therefore run in client-major, pair order — the same
+order as the segment-sum oracle in kernels/ref.py, bit for bit, duplicate
+indices included (scatter-add).
 
-VMEM note: the output block is the full padded leaf, so a single-leaf
-aggregate is VMEM-bounded at ~leaf_bytes (fp32) + K·k pairs.  That holds
-comfortably for per-leaf aggregation of the assigned architectures; a
-future hierarchical (regional) aggregator reusing this kernel at larger
-fan-in would tile the output over row blocks and mask each client's
-pairs per block — the k-cost hook the ROADMAP's million-client item
-builds on.
+Every block shape obeys the 8 × 128 tiling: pairs arrive as
+(K, rows, 128) chunks of ``CHUNK_ROWS`` rows, and the per-client weights
+ride in SMEM as scalar-prefetch operands.  Each block visits every pair,
+so the work is (blocks × K · k); binning the pairs by block first is the
+k-cost version.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
+BLOCK_ROWS = 512          # output rows per block: 512×128 fp32 = 256 KiB
+CHUNK_ROWS = 64           # wire rows per step: 64×128 pairs
 
 
-def _sparse_reduce_kernel(w_ref, v_ref, i_ref, o_ref):
-    # w (1, LANE) fp32 — one client's weight, broadcast along lanes for
-    # lane alignment; v/i (1, kp); o (rows, LANE) fp32, revisited across
-    # the K grid steps (constant index map): the ref is the accumulator.
-    @pl.when(pl.program_id(0) == 0)
+def _sparse_reduce_kernel(w_ref, v_ref, i_ref, o_ref, *, block_elems):
+    # w (K,) fp32 in SMEM; v/i (1, chunk, LANE) — one chunk of one
+    # client's pairs; o (block, LANE) fp32, revisited across the client
+    # and chunk axes: the ref is the accumulator.
+    r, k, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when((k == 0) & (c == 0))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    wv = w_ref[0, 0] * v_ref[...].reshape(-1).astype(jnp.float32)
+    local = i_ref[0].reshape(-1) - r * block_elems
+    inb = (local >= 0) & (local < block_elems)
+    wv = jnp.where(inb, w_ref[k] * v_ref[0].reshape(-1).astype(jnp.float32),
+                   0.0)
     flat = o_ref[...].reshape(-1)
-    flat = flat.at[i_ref[...].reshape(-1)].add(wv)
+    flat = flat.at[jnp.where(inb, local, 0)].add(wv)
     o_ref[...] = flat.reshape(o_ref.shape)
 
 
@@ -58,15 +68,26 @@ def sparse_reduce_2d(values, indices, weights, rows, interpret=False):
     (value 0, index 0) pairs — weighted zeros accumulate as exact +0.0).
     The caller casts the fp32 result to the wire dtype (cast-on-write)."""
     k_clients, kp = values.shape
-    w2d = jnp.broadcast_to(weights.astype(jnp.float32)[:, None],
-                           (k_clients, LANE))
+    krows = kp // LANE
+    chunk = min(krows, CHUNK_ROWS)
+    pad = (-krows) % chunk
+    if pad:
+        # more (value 0, index 0) filler rows: exact +0.0 adds
+        values = jnp.pad(values, ((0, 0), (0, pad * LANE)))
+        indices = jnp.pad(indices, ((0, 0), (0, pad * LANE)))
+        krows += pad
+    block = min(rows, BLOCK_ROWS)
+    pair_spec = pl.BlockSpec((1, chunk, LANE), lambda r, k, c, w: (k, c, 0))
     return pl.pallas_call(
-        _sparse_reduce_kernel,
-        grid=(k_clients,),
-        in_specs=[pl.BlockSpec((1, LANE), lambda i: (i, 0)),
-                  pl.BlockSpec((1, kp), lambda i: (i, 0)),
-                  pl.BlockSpec((1, kp), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((rows, LANE), lambda i: (0, 0)),
+        functools.partial(_sparse_reduce_kernel, block_elems=block * LANE),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(rows, block), k_clients, krows // chunk),
+            in_specs=[pair_spec, pair_spec],
+            out_specs=pl.BlockSpec((block, LANE),
+                                   lambda r, k, c, w: (r, 0))),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
         interpret=interpret,
-    )(w2d, values, indices)
+    )(weights.astype(jnp.float32),
+      values.reshape(k_clients, krows, LANE),
+      indices.reshape(k_clients, krows, LANE))

@@ -17,7 +17,7 @@ from repro.configs import (ARCHS, SHAPES, get_arch, long_context_variant,
 from repro.configs.base import FedConfig, RunConfig
 from repro.launch import inputs as I
 from repro.launch import roofline as R
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.serve import make_prefill_step, make_serve_step
 from repro.launch.train import make_train_step
 
@@ -55,14 +55,14 @@ def lower_one(arch_id: str, shape_name: str, multi_pod: bool,
     fed = fed or _fed_for(shape, arch_id)
     run = run or _run_for(arch_id)
     if mesh_override is not None:
-        mesh = jax.make_mesh(tuple(mesh_override),
-                             ("data", "model") if len(mesh_override) == 2
-                             else ("pod", "data", "model"))
+        mesh = make_mesh(mesh_override,
+                         ("data", "model") if len(mesh_override) == 2
+                         else ("pod", "data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             # NOTE (§Perf iteration 11, refuted): turning TP off for sub-1B
             # archs idles the model axis at this round decomposition

@@ -12,18 +12,19 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.models import layers as L
 
 
 def _constrain(x, *spec):
-    """Best-effort sharding constraint: binds to the ambient mesh under the
-    dry-run / pod engine, no-op on meshless CPU tests."""
-    try:
-        from jax.sharding import PartitionSpec as P
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
+    """Pin `x` to `spec` on the mesh declared with ``jax.set_mesh`` (the
+    dry-run, a sharded pod round).  Without a declared mesh there is
+    nothing to pin to and `x` passes through; under one, a spec the mesh
+    cannot take raises."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def moe_init(key, cfg, dtype=jnp.float32):

@@ -1,0 +1,138 @@
+"""Compile the round's Pallas kernels for a described TPU v5e chip.
+
+Nothing runs: each test lowers a kernel at the widths the chip smoke test
+uses (qwen3-4b: d_model 2560, d_ff 9728, 32 q / 8 kv heads of 128) and
+compiles it with the TPU compiler for one chip of a described ``v5e:2x2``
+topology.  A kernel the chip's compiler refuses (a block shape off the
+8 × 128 tiling, a scalar it cannot extract, too much VMEM) fails here,
+at no chip time.  Each test asserts the program holds the Mosaic kernel
+(``tpu_custom_call``), so a silent fallback to XLA fails too.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import compress as _cp
+from repro.kernels import fedadc_update as _fu
+from repro.kernels import ops
+from repro.kernels import weighted_reduce as _wr
+
+D_MODEL, D_FF = 2560, 9728
+ROWS = D_MODEL * D_FF // ops.LANE          # the MLP leaf as (rows, 128) tiles
+DTYPES = [jnp.float32, jnp.bfloat16]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The ops.py wrappers pick interpret mode off the TPU; these compiles
+    target the TPU, so the wrappers must emit the Mosaic kernel."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("kernel", ["fused_axpy", "local_update",
+                                    "server_update"])
+def test_fedadc_update_compiles(one_chip, kernel, dtype):
+    leaf = _sds((ROWS, ops.LANE), dtype, one_chip)
+    fns = {
+        "fused_axpy": lambda x, y: _fu.fused_axpy_2d(x, y, -0.05),
+        "local_update": lambda t, g, m: _fu.local_update_2d(t, g, m, 0.05),
+        "server_update": lambda t, m, d: _fu.server_update_2d(
+            t, m, d, 0.1, 0.05),
+    }
+    n_in = 2 if kernel == "fused_axpy" else 3
+    _compile(fns[kernel], *([leaf] * n_in))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_weighted_reduce_compiles(one_chip, dtype):
+    _compile(_wr.weighted_reduce_2d,
+             _sds((4, ROWS, ops.LANE), dtype, one_chip),
+             _sds((4,), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("kernel", ["threshold", "qsgd"])
+def test_compress_compiles(one_chip, kernel, dtype):
+    leaf = _sds((ROWS, ops.LANE), dtype, one_chip)
+    scalar = _sds((), dtype, one_chip)
+    if kernel == "threshold":
+        _compile(_cp.threshold_select_2d, leaf, scalar)
+    else:
+        _compile(lambda v, u, s: _cp.qsgd_2d(v, u, s, 255), leaf, leaf,
+                 scalar)
+
+
+def _qkv(one_chip, L=2048):
+    return (_sds((1, L, 32, 128), jnp.bfloat16, one_chip),
+            _sds((1, L, 8, 128), jnp.bfloat16, one_chip),
+            _sds((1, L, 8, 128), jnp.bfloat16, one_chip))
+
+
+def test_flash_attention_forward_compiles(one_chip, mosaic):
+    _compile(lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
+             *_qkv(one_chip))
+
+
+def test_flash_attention_backward_compiles(one_chip, mosaic):
+    """The custom VJP as a training step runs it: the kernel forward and
+    the float32-oracle backward in one program."""
+    q, k, v = _qkv(one_chip)
+
+    def fwd_bwd(q, k, v, g):
+        out, pullback = jax.vjp(
+            lambda *a: ops.flash_attention(*a, causal=True), q, k, v)
+        return out, pullback(g)
+    compiled = _compile(fwd_bwd, q, k, v, q)
+    out, grads = compiled.out_info
+    assert out.shape == q.shape
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+
+
+@pytest.mark.xfail(
+    strict=True, raises=NotImplementedError,
+    reason="Mosaic: Unimplemented primitive in Pallas TPU lowering for "
+           "KernelType.TC: scatter-add")
+def test_sparse_reduce_compiles(one_chip, mosaic):
+    """K=4 clients' top-1% wires of the MLP leaf, segment-summed."""
+    n = D_MODEL * D_FF
+    k = int(np.ceil(0.01 * n))
+    _compile(lambda v, i, w: ops.sparse_weighted_delta_reduce(
+                 v, i, w, (D_MODEL, D_FF), jnp.bfloat16),
+             _sds((4, k), jnp.bfloat16, one_chip),
+             _sds((4, k), jnp.int32, one_chip),
+             _sds((4,), jnp.float32, one_chip))

@@ -355,6 +355,7 @@ class TestSparseReduce:
         ((4096,), jnp.bfloat16, 96, 409),
         ((17,), jnp.float32, 3, 5),        # k-pad + n-pad, tiny leaf
         ((), jnp.float32, 4, 1),           # scalar leaf
+        ((70000,), jnp.float32, 3, 9000),  # 2 output row blocks, 2 chunks
     ])
     def test_pallas_matches_ref_bitwise(self, shape, dtype, K, k):
         """Kernel and oracle apply the weighted updates in the same

@@ -94,3 +94,72 @@ def test_mixed_precision_round_preserves_master_dtype():
         assert leaf.dtype == jnp.float32      # f32 master survives
     for leaf in jax.tree.leaves(new_state["server"]["m"]):
         assert leaf.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("window", [0, 64])
+def test_flash_attention_gradients_match_oracle(window):
+    """The kernel's custom VJP is the float32 oracle's: gradients through
+    ops.flash_attention equal those through ref.flash_attention."""
+    from repro.kernels import ops, ref
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (2, 256, 4, 64))
+    k = jax.random.normal(ks[1], (2, 256, 2, 64))
+    v = jax.random.normal(ks[2], (2, 256, 2, 64))
+    g = jax.random.normal(ks[3], q.shape)
+
+    def kernel(q, k, v):
+        return jnp.sum(ops.flash_attention(q, k, v, causal=True,
+                                           window=window) * g)
+
+    def oracle(q, k, v):
+        out = ref.flash_attention(*(jnp.moveaxis(t, 1, 2) for t in (q, k, v)),
+                                  causal=True, window=window)
+        return jnp.sum(jnp.moveaxis(out, 1, 2) * g)
+    got = jax.grad(kernel, (0, 1, 2))(q, k, v)
+    exp = jax.grad(oracle, (0, 1, 2))(q, k, v)
+    for a, b in zip(got, exp):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-6)
+
+
+def test_model_gradients_with_kernels_match_jnp():
+    """Gradients of the LM loss through the flash-attention kernel match
+    the jnp attention path."""
+    cfg = ARCHS["qwen3-4b"].reduced()
+    model = get_model(cfg)
+    params = model.init(jax.random.PRNGKey(0), cfg)
+    batch = _mk_batch(cfg)
+
+    def grads(use_pallas):
+        return jax.grad(lambda p: model.loss_fn(p, batch, cfg,
+                                                use_pallas)[0])(params)
+    a, b = grads(False), grads(True)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_allclose(np.asarray(y), np.asarray(x), rtol=1e-4,
+                                   atol=1e-6)
+
+
+def test_pod_round_with_kernels_matches_jnp_round():
+    """A use_pallas=True pod round traces (the flash-attention kernel is
+    differentiated through its custom VJP) and moves θ as the jnp round
+    does, in float32."""
+    cfg = ARCHS["qwen3-4b"].reduced()
+    run = RunConfig(remat="none", param_dtype="float32",
+                    compute_dtype="float32")
+    batch = jax.tree.map(lambda x: jnp.broadcast_to(x, (1, 2, 2) + x.shape),
+                         _mk_batch(cfg, 2, 128))
+    deltas, losses = [], []
+    for use_pallas in (False, True):
+        fed = FedConfig(strategy="fedadc", clients_per_round=2,
+                        local_steps=2, eta=0.05, use_pallas=use_pallas)
+        state = init_state(jax.random.PRNGKey(0), cfg, fed, run)
+        new_state, metrics = jax.jit(make_train_step(cfg, fed, run))(
+            state, batch)
+        losses.append(float(metrics["loss"]))
+        deltas.append(jax.tree.map(lambda a, b: np.asarray(a - b),
+                                   new_state["params"], state["params"]))
+    np.testing.assert_allclose(losses[1], losses[0], rtol=1e-5)
+    num = sum(np.sum((a - b) ** 2) for a, b in
+              zip(jax.tree.leaves(deltas[1]), jax.tree.leaves(deltas[0])))
+    den = sum(np.sum(a ** 2) for a in jax.tree.leaves(deltas[0]))
+    assert np.sqrt(num / den) < 1e-4

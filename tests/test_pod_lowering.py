@@ -3,6 +3,7 @@
 pin the ShapeDtypeStruct/sharding plumbing so it cannot rot)."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import ARCHS
@@ -108,3 +109,26 @@ def test_round_decomposition_exact():
     from repro.configs.base import SHAPES
     CP, CS, H, b = round_decomposition(SHAPES["train_4k"], fed, mesh, False)
     assert CP * CS == 4 and H == 4 and CP * CS * H * b == 256
+
+
+def test_meshes_use_auto_axes():
+    """Every mesh builder makes Auto axes: under explicit axes the
+    embedding gather over a sharded table is a type error."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_mesh
+    for mesh in (make_host_mesh(), make_mesh((1, 1), ("data", "model"))):
+        assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
+def test_moe_constraint_only_under_a_declared_mesh():
+    """Without a declared mesh the MoE dispatch constraint passes its input
+    through; under one, a spec naming an axis the mesh lacks raises
+    instead of silently un-sharding."""
+    from repro.models.moe import _constrain
+    x = jnp.ones((4, 8))
+    assert _constrain(x, "no_such_axis", None) is x
+    with jax.set_mesh(make_host_mesh()):
+        y = jax.jit(lambda a: _constrain(a, "data", None))(x)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
+        with pytest.raises(Exception, match="no_such_axis"):
+            jax.jit(lambda a: _constrain(a, "no_such_axis", None))(x)

@@ -11,9 +11,8 @@ from repro.launch import roofline as R
 from repro.models.registry import get_model
 from repro.sharding import specs as S
 
-# JAX 0.4.37 AbstractMesh takes ((name, size), ...) pair tuples
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _specs_for(arch):

@@ -190,15 +190,21 @@ def make_train_step(mcfg: ModelConfig, fed: FedConfig, run: RunConfig,
             theta, extra = carry
 
             def grad_fn(th, _):
-                l, g = jax.value_and_grad(loss_fn)(th, sb, theta_t, rho)
+                with jax.named_scope("fedadc.fwd_bwd"):
+                    l, g = jax.value_and_grad(loss_fn)(th, sb, theta_t, rho)
                 return g, l
             theta, extra, l = strategy.local_step(theta, ctx, grad_fn, None,
                                                   fed, extra)
             return (theta, extra), l
 
         extra0 = strategy.init_extra(theta_t, fed)
-        (theta_H, _), ls = jax.lax.scan(local, (theta_t, extra0), cb)
-        return T.sub(theta_t, theta_H), jnp.mean(ls)
+        # the local steps' loop is the local update's phase, so the ops the
+        # compiler derives from the loop body land in it too
+        with jax.named_scope("fedadc.local_update"):
+            (theta_H, _), ls = jax.lax.scan(local, (theta_t, extra0), cb)
+        with jax.named_scope("fedadc.uplink"):
+            delta = T.sub(theta_t, theta_H)
+        return delta, jnp.mean(ls)
 
     def per_group(theta_t, ctx, ref, cbs, gkey, efs=None):
         """cbs: dict with leading (CS, H, b) — serial clients, weighted
@@ -224,40 +230,48 @@ def make_train_step(mcfg: ModelConfig, fed: FedConfig, run: RunConfig,
             else:
                 acc, wsum = carry
             d, l = client_delta(theta_t, ctx, cb)
-            new_ef = ef if efs is not None else jnp.zeros(())
-            if transported:
-                # sparse-native: encode only — the (values, indices) wire
-                # is scatter-accumulated below at k-cost, and the EF
-                # residual from encode is the exact complement the
-                # roundtrip would return (the scan carry stays
-                # dense-output/sparse-input)
-                up = transport.uplink_encode if sparse_native \
-                    else transport.uplink
-                d, new_ef = up(d, T.zeros_like(d) if ef is None else ef, ck)
-                if efs is None:
-                    new_ef = jnp.zeros(())   # residual not carried
-            w = A.streaming_weight(d, ref, fed.aggregator, fed.drag_lambda)
-            # Σ w·Δ accumulates in fp32 regardless of the wire dtype: a
-            # bf16 running sum loses the late clients to rounding once the
-            # partial sum's ulp outgrows the increments; cast on write
-            # happens after the cross-pod aggregation below
-            if sparse_native:
-                # per coordinate this is the same client-ordered fp32 add
-                # chain as the dense decode path (whose off-support adds
-                # are exact +0.0 no-ops), so the two are bit-identical
-                acc = jax.tree.map(
-                    lambda wl, a: a.reshape(-1).at[wl.indices].add(
-                        w * wl.values.astype(jnp.float32)).reshape(a.shape),
-                    d, acc, is_leaf=A.is_sparse_leaf)
-            else:
-                acc = jax.tree.map(
-                    lambda a, di: a + w * di.astype(jnp.float32), acc, d)
+            with jax.named_scope("fedadc.uplink"):
+                new_ef = ef if efs is not None else jnp.zeros(())
+                if transported:
+                    # sparse-native: encode only — the (values, indices)
+                    # wire is scatter-accumulated below at k-cost, and the
+                    # EF residual from encode is the exact complement the
+                    # roundtrip would return (the scan carry stays
+                    # dense-output/sparse-input)
+                    up = transport.uplink_encode if sparse_native \
+                        else transport.uplink
+                    d, new_ef = up(d, T.zeros_like(d) if ef is None else ef,
+                                   ck)
+                    if efs is None:
+                        new_ef = jnp.zeros(())   # residual not carried
+            with jax.named_scope("fedadc.accumulate"):
+                w = A.streaming_weight(d, ref, fed.aggregator,
+                                       fed.drag_lambda)
+                # Σ w·Δ accumulates in fp32 regardless of the wire dtype: a
+                # bf16 running sum loses the late clients to rounding once
+                # the partial sum's ulp outgrows the increments; cast on
+                # write happens after the cross-pod aggregation below
+                if sparse_native:
+                    # per coordinate this is the same client-ordered fp32
+                    # add chain as the dense decode path (whose off-support
+                    # adds are exact +0.0 no-ops), so the two are
+                    # bit-identical
+                    acc = jax.tree.map(
+                        lambda wl, a: a.reshape(-1).at[wl.indices].add(
+                            w * wl.values.astype(jnp.float32)
+                        ).reshape(a.shape),
+                        d, acc, is_leaf=A.is_sparse_leaf)
+                else:
+                    acc = jax.tree.map(
+                        lambda a, di: a + w * di.astype(jnp.float32), acc, d)
+                wsum = wsum + w
+                if with_metrics:
+                    # the only telemetry cost in the scan: one fp32 scalar,
+                    # Σ w·||Δ||², for the streaming-dispersion identity
+                    sqsum = sqsum + drift_metrics.streaming_sq_norm(d, w)
             if with_metrics:
-                # the only telemetry cost in the scan: one fp32 scalar,
-                # Σ w·||Δ||², for the streaming-dispersion identity
-                sqsum = sqsum + drift_metrics.streaming_sq_norm(d, w)
-                return (acc, wsum + w, sqsum), (l, new_ef)
-            return (acc, wsum + w), (l, new_ef)
+                return (acc, wsum, sqsum), (l, new_ef)
+            return (acc, wsum), (l, new_ef)
         acc0 = (T.cast(T.zeros_like(theta_t), jnp.float32), jnp.zeros(()))
         if with_metrics:
             acc0 = acc0 + (jnp.zeros(()),)
@@ -279,49 +293,54 @@ def make_train_step(mcfg: ModelConfig, fed: FedConfig, run: RunConfig,
         # all-gathers and activation traffic; Δ̄ is upcast before the f32
         # server update, which preserves the momentum-accumulation
         # precision the FedADC recursion needs.
-        theta_t, server_ctx_state, ctx, mixed = _broadcast_inputs(
-            strategy, theta_master, state["server"], fed, run)
-        ref = A.reference_direction(server_ctx_state) \
-            if fed.aggregator == "drag" else None
-        CP, CSn = batch["tokens"].shape[:2]
-        # per-round compression randomness, deterministic in (run seed,
-        # round index) so replicate experiments draw independent noise
-        round_key = jax.random.fold_in(jax.random.PRNGKey(run.seed),
-                                       state["round"])
-        pod_keys = jax.random.split(round_key, CP)
-        new_dref = None
-        if transport.down is not None:
-            # clients everywhere train on the broadcast reconstruction;
-            # only the lossy delta codec keeps reference state, and it
-            # rides state["refs"] ("refs" membership is a static Python
-            # fact — the lossless config traces the ref-free graph)
-            dkey = jax.random.fold_in(round_key, 0xD0) if lossy_down \
-                else None
-            dref = state["refs"]["downlink"] if "refs" in state else None
-            theta_t, ctx, new_dref = transport.broadcast(
-                theta_t, ctx, dkey, dref)
-        if ef_enabled:
-            if client_ids is None:
-                # default identification: slot i of the round is client i
-                client_ids = jnp.arange(CP * CSn,
-                                        dtype=jnp.int32).reshape(CP, CSn)
-            efs = jax.tree.map(
-                lambda x: x.reshape((CP, CSn) + x.shape[1:]),
-                CS.sharded_gather(state["clients"]["ef"],
-                                  client_ids.reshape(-1)))
-        else:
-            efs = None
+        with jax.named_scope("fedadc.broadcast"):
+            theta_t, server_ctx_state, ctx, mixed = _broadcast_inputs(
+                strategy, theta_master, state["server"], fed, run)
+            ref = A.reference_direction(server_ctx_state) \
+                if fed.aggregator == "drag" else None
+            CP, CSn = batch["tokens"].shape[:2]
+            # per-round compression randomness, deterministic in (run
+            # seed, round index) so replicate experiments draw independent
+            # noise
+            round_key = jax.random.fold_in(jax.random.PRNGKey(run.seed),
+                                           state["round"])
+            pod_keys = jax.random.split(round_key, CP)
+            new_dref = None
+            if transport.down is not None:
+                # clients everywhere train on the broadcast reconstruction;
+                # only the lossy delta codec keeps reference state, and it
+                # rides state["refs"] ("refs" membership is a static Python
+                # fact — the lossless config traces the ref-free graph)
+                dkey = jax.random.fold_in(round_key, 0xD0) if lossy_down \
+                    else None
+                dref = state["refs"]["downlink"] if "refs" in state \
+                    else None
+                theta_t, ctx, new_dref = transport.broadcast(
+                    theta_t, ctx, dkey, dref)
+            if ef_enabled:
+                if client_ids is None:
+                    # default identification: slot i of the round is
+                    # client i
+                    client_ids = jnp.arange(
+                        CP * CSn, dtype=jnp.int32).reshape(CP, CSn)
+                efs = jax.tree.map(
+                    lambda x: x.reshape((CP, CSn) + x.shape[1:]),
+                    CS.sharded_gather(state["clients"]["ef"],
+                                      client_ids.reshape(-1)))
+            else:
+                efs = None
         if CP == 1:
             squeezed = jax.tree.map(lambda x: x[0], batch)
             efs0 = None if efs is None else jax.tree.map(lambda x: x[0], efs)
             acc, wsum, loss, new_efs, sqsum = per_group(
                 theta_t, ctx, ref, squeezed, pod_keys[0], efs0)
-            group_means = jax.tree.map(
-                lambda a: (a / wsum.astype(a.dtype))[None], acc)
-            gweights = wsum[None]
-            sq_total, w_total = sqsum, wsum
-            if efs is not None:
-                new_efs = jax.tree.map(lambda x: x[None], new_efs)
+            with jax.named_scope("fedadc.aggregate"):
+                group_means = jax.tree.map(
+                    lambda a: (a / wsum.astype(a.dtype))[None], acc)
+                gweights = wsum[None]
+                sq_total, w_total = sqsum, wsum
+                if efs is not None:
+                    new_efs = jax.tree.map(lambda x: x[None], new_efs)
         else:
             if efs is None:
                 accs, wsums, losses, new_efs, sqsums = jax.vmap(
@@ -332,12 +351,13 @@ def make_train_step(mcfg: ModelConfig, fed: FedConfig, run: RunConfig,
                     lambda cbs, gk, e: per_group(theta_t, ctx, ref, cbs,
                                                  gk, e)
                 )(batch, pod_keys, efs)
-            group_means = jax.tree.map(
-                lambda a: a / wsums.reshape((-1,) + (1,) * (a.ndim - 1)
-                                            ).astype(a.dtype), accs)
-            gweights = wsums
-            sq_total, w_total = jnp.sum(sqsums), jnp.sum(wsums)
-            loss = jnp.mean(losses)
+            with jax.named_scope("fedadc.aggregate"):
+                group_means = jax.tree.map(
+                    lambda a: a / wsums.reshape(
+                        (-1,) + (1,) * (a.ndim - 1)).astype(a.dtype), accs)
+                gweights = wsums
+                sq_total, w_total = jnp.sum(sqsums), jnp.sum(wsums)
+                loss = jnp.mean(losses)
         # per-pod weighted means recombine exactly through the shared hook:
         # Δ̄ = Σ_p W_p·Δ̄_p / Σ_p W_p = Σ_i w_i·Δ_i / Σ_i w_i by linearity.
         # The per-group sums arrive as fp32 accumulators; the mixed round
@@ -347,41 +367,46 @@ def make_train_step(mcfg: ModelConfig, fed: FedConfig, run: RunConfig,
         # partials before the global combine (identity at R=1 — DESIGN.md
         # §Fleet); each pod is already a stage-1 unit, so nothing changes
         # inside the client-serial scan.
-        if fed.fleet_regions > 0:
-            mean_delta = FH.hierarchical_combine(group_means, gweights, fed,
-                                                 strategy)
-        else:
-            mean_delta = strategy.server_aggregate(group_means, gweights, fed)
-        mean_delta = T.cast(mean_delta,
-                            jnp.float32 if mixed else jnp.dtype(
-                                run.param_dtype))
-        new_params, new_server = strategy.server_update(
-            state["server"], theta_master, mean_delta, fed)
-        new_state = {"params": new_params, "server": new_server,
-                     "round": state["round"] + 1}
-        if "refs" in state:
-            new_state["refs"] = {"downlink": new_dref}
-        if ef_enabled:
-            flat_new = jax.tree.map(
-                lambda x: x.reshape((-1,) + x.shape[2:]), new_efs)
-            new_state["clients"] = {"ef": CS.sharded_scatter(
-                state["clients"]["ef"], client_ids.reshape(-1), flat_new)}
-        aux = {"loss": loss}
-        if with_metrics:
-            metrics = {
-                "delta_dispersion": drift_metrics.streaming_dispersion(
-                    sq_total, w_total, mean_delta),
-                "update_norm": drift_metrics.update_norm(mean_delta),
-            }
-            if "m" in state["server"]:
-                metrics["momentum_alignment"] = \
-                    drift_metrics.momentum_alignment(state["server"]["m"],
-                                                     mean_delta)
+        with jax.named_scope("fedadc.aggregate"):
+            if fed.fleet_regions > 0:
+                mean_delta = FH.hierarchical_combine(group_means, gweights,
+                                                     fed, strategy)
+            else:
+                mean_delta = strategy.server_aggregate(group_means, gweights,
+                                                       fed)
+            mean_delta = T.cast(mean_delta,
+                                jnp.float32 if mixed else jnp.dtype(
+                                    run.param_dtype))
+        with jax.named_scope("fedadc.server_update"):
+            new_params, new_server = strategy.server_update(
+                state["server"], theta_master, mean_delta, fed)
+            new_state = {"params": new_params, "server": new_server,
+                         "round": state["round"] + 1}
+            if "refs" in state:
+                new_state["refs"] = {"downlink": new_dref}
             if ef_enabled:
-                metrics["ef_residual_norm"] = drift_metrics.ef_residual_norm(
-                    jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]),
-                                 new_efs))
-            aux["telemetry"] = metrics
+                flat_new = jax.tree.map(
+                    lambda x: x.reshape((-1,) + x.shape[2:]), new_efs)
+                new_state["clients"] = {"ef": CS.sharded_scatter(
+                    state["clients"]["ef"], client_ids.reshape(-1),
+                    flat_new)}
+            aux = {"loss": loss}
+            if with_metrics:
+                metrics = {
+                    "delta_dispersion": drift_metrics.streaming_dispersion(
+                        sq_total, w_total, mean_delta),
+                    "update_norm": drift_metrics.update_norm(mean_delta),
+                }
+                if "m" in state["server"]:
+                    metrics["momentum_alignment"] = \
+                        drift_metrics.momentum_alignment(
+                            state["server"]["m"], mean_delta)
+                if ef_enabled:
+                    metrics["ef_residual_norm"] = \
+                        drift_metrics.ef_residual_norm(jax.tree.map(
+                            lambda x: x.reshape((-1,) + x.shape[2:]),
+                            new_efs))
+                aux["telemetry"] = metrics
         return new_state, aux
 
     # measured-byte accounting (bugfix): the pod engine drives real wire

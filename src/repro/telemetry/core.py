@@ -24,7 +24,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
-from repro.telemetry.export import JsonlSink, prometheus_text
+from repro.telemetry.export import JsonlSink
 from repro.telemetry.latency import latency_summary, request_itl
 from repro.telemetry.tracer import Counters, Histogram, Tracer
 
@@ -131,9 +131,6 @@ class Telemetry:
         self.emit("summary", **{k: v for k, v in s.items()
                                 if k != "engine"}, **extra)
         return s
-
-    def prometheus(self) -> str:
-        return prometheus_text(self.counters, self.histograms)
 
     def close(self) -> None:
         if self._sink is not None:

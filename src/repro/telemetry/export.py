@@ -1,23 +1,14 @@
-"""Telemetry exporters: JSONL event sink and Prometheus-style text dump
-(DESIGN.md §Telemetry).
+"""Telemetry exporter: the JSONL event sink (DESIGN.md §Telemetry).
 
 ``JsonlSink`` writes one schema-validated JSON object per line — append-only,
 flushed per event so a crashed run keeps everything emitted before the
-crash.  ``prometheus_text`` renders a ``Counters`` snapshot (plus optional
-histograms) in the Prometheus exposition text format, with metric names
-sanitised to the ``[a-zA-Z_][a-zA-Z0-9_]*`` charset (dots become
-underscores).  Both are zero-dependency.
+crash.  Zero-dependency.
 """
 from __future__ import annotations
 
 import json
-import re
-from typing import Dict, Optional
 
 from repro.telemetry.schema import validate_event
-from repro.telemetry.tracer import Counters, Histogram
-
-_NAME_OK = re.compile(r"[^a-zA-Z0-9_]")
 
 
 class JsonlSink:
@@ -47,39 +38,3 @@ class JsonlSink:
     def __exit__(self, *exc):
         self.close()
         return False
-
-
-def _sanitize(name: str) -> str:
-    name = _NAME_OK.sub("_", name)
-    if not name or name[0].isdigit():
-        name = "_" + name
-    return name
-
-
-def prometheus_text(counters: Counters,
-                    histograms: Optional[Dict[str, Histogram]] = None,
-                    prefix: str = "repro") -> str:
-    """A ``Counters`` snapshot (+ histograms) in Prometheus text format.
-
-    Counter semantics are not tracked per name, so everything is exposed as
-    an untyped gauge — the dump is for scraping/diffing, not for a real
-    Prometheus server's rate() math.  Histograms expose the standard
-    ``_bucket{le=...}`` / ``_sum`` / ``_count`` triple (cumulative buckets,
-    closing ``+Inf``).
-    """
-    lines = []
-    for name, value in sorted(counters.snapshot().items()):
-        metric = _sanitize(f"{prefix}_{name}")
-        lines.append(f"# TYPE {metric} gauge")
-        lines.append(f"{metric} {value}")
-    for name, hist in sorted((histograms or {}).items()):
-        metric = _sanitize(f"{prefix}_{name}")
-        lines.append(f"# TYPE {metric} histogram")
-        cum = 0
-        for i, b in enumerate(hist.bins):
-            cum += b
-            lines.append(f'{metric}_bucket{{le="{i}"}} {cum}')
-        lines.append(f'{metric}_bucket{{le="+Inf"}} {hist.count}')
-        lines.append(f"{metric}_sum {hist.total}")
-        lines.append(f"{metric}_count {hist.count}")
-    return "\n".join(lines) + ("\n" if lines else "")

@@ -10,7 +10,10 @@ the sync/pod engines), ``local_train`` / ``aggregate`` / ``transport.encode``
 (the async engine's separate dispatch-group, flush, and broadcast calls),
 ``prefill_chunk`` / ``decode_step`` (the serving engine) — phases fused
 inside one jit call cannot be separated without adding dispatches, and the
-tracer never does.
+tracer never does.  An enabled span is also a ``jax.profiler.TraceAnnotation``
+under the same nested name, so a profiler trace shows the engines' spans on
+the device ops' clock (inside the round, the program's ``named_scope``
+phases name the device ops themselves).
 
 ``Counters`` is the one registry every byte/count statistic lives behind:
 ``Transport`` accounts its four wire counters straight into it (the
@@ -22,9 +25,9 @@ unbounded ``staleness_seen`` list: fixed integer bins plus an overflow
 bucket, with exact count/mean/max tracked alongside — O(bins) memory no
 matter how many observations arrive.
 
-Everything here is zero-dependency host Python; the disabled tracer's
-``span`` is a shared no-op context manager, so telemetry-off engines pay
-one attribute lookup per span site and touch no device state.
+Everything here is host Python; the disabled tracer's ``span`` is a shared
+no-op context manager, so telemetry-off engines pay one attribute lookup
+per span site, touch no device state and write nothing to a trace.
 """
 from __future__ import annotations
 
@@ -32,6 +35,8 @@ import contextlib
 import time
 from collections import deque
 from typing import Dict, Iterable, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class _NullSpan:
@@ -50,18 +55,22 @@ _NULL_SPAN = _NullSpan()
 class Span:
     """One timed host-side phase.  ``sync`` (any pytree of jax arrays) is
     blocked on before the clock stops, so the duration covers the device
-    work the phase dispatched, not just the Python that launched it."""
+    work the phase dispatched, not just the Python that launched it.  The
+    span is a profiler ``TraceAnnotation`` of the same name over the same
+    stretch, sync included."""
 
-    __slots__ = ("tracer", "name", "sync", "t0")
+    __slots__ = ("tracer", "name", "sync", "t0", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, sync=None):
         self.tracer = tracer
         self.name = name
         self.sync = sync
         self.t0 = 0.0
+        self.annotation = TraceAnnotation(name)
 
     def __enter__(self):
         self.tracer._stack.append(self.name)
+        self.annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -70,6 +79,7 @@ class Span:
             import jax
             jax.block_until_ready(self.sync)
         dur = time.perf_counter() - self.t0
+        self.annotation.__exit__(*exc)
         self.tracer._stack.pop()
         self.tracer._record(self.name, dur)
         return False

@@ -20,7 +20,7 @@ from repro.serving.request import RequestOutput
 from repro.telemetry import (Counters, Histogram, JsonlSink, Telemetry,
                              Tracer, delta_dispersion, ef_residual_norm,
                              latency_summary, momentum_alignment,
-                             prometheus_text, request_itl, round_metrics,
+                             request_itl, round_metrics,
                              streaming_dispersion, streaming_sq_norm,
                              update_norm, validate_event, validate_jsonl)
 
@@ -100,6 +100,26 @@ class TestTracer:
         with tr.span("round"):
             pass
         assert tr.timings("round") == [] and tr.summary() == {}
+
+    def test_spans_are_profiler_trace_annotations(self, tmp_path):
+        tr = Tracer(enabled=True)
+        off = Tracer(enabled=False)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("round"):
+                with tr.span("local_train"):
+                    pass
+            with off.span("hidden"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        path, = tmp_path.rglob("*.xplane.pb")
+        names = {e.name for plane in
+                 jax.profiler.ProfileData.from_file(str(path)).planes
+                 for line in plane.lines for e in line.events}
+        assert {"round", "round/local_train"} <= names
+        assert "hidden" not in names
+        assert off.span("x") is off.span("y")     # the shared no-op span
 
     def test_bounded_reservoir_exact_count(self):
         tr = Tracer(enabled=True, maxlen=8)
@@ -201,22 +221,6 @@ class TestJsonlSink:
     def test_invalid_event_raises(self):
         with pytest.raises(ValueError):
             JsonlSink(io.StringIO()).emit({"kind": "round"})
-
-
-class TestPrometheus:
-    def test_counters_and_histogram_text(self):
-        c = Counters()
-        c.inc("transport.uplink_bytes", 128)
-        h = Histogram(n_bins=2)
-        h.observe_many([0, 1, 1])
-        text = prometheus_text(c, {"staleness": h})
-        assert "repro_transport_uplink_bytes 128" in text
-        assert 'repro_staleness_bucket{le="+Inf"} 3' in text
-        assert "repro_staleness_count 3" in text
-        # buckets are cumulative
-        lines = [l for l in text.splitlines() if "_bucket" in l]
-        counts = [int(l.rsplit(" ", 1)[1]) for l in lines]
-        assert counts == sorted(counts)
 
 
 # ---------------------------------------------------------------------------

@@ -81,12 +81,9 @@ def _from_tiles(t, pad, shape, dtype):
 
 
 def fused_axpy(x, y, a, spec=P()):
-    """x + a·y on a single leaf laid out as `spec` under a mesh."""
-    def leaf(x, y):
-        xt, pad = _as_tiles(x)
-        yt, _ = _as_tiles(y)
-        out = _fu.fused_axpy_2d(xt, yt, a, interpret=_interpret())
-        return _from_tiles(out, pad, x.shape, x.dtype)
+    """x + a·y on a single leaf laid out as `spec` under a mesh; the
+    kernel takes each (shard of a) leaf in its own shape and layout."""
+    leaf = functools.partial(_fu.fused_axpy, a=a, interpret=_interpret())
     return _on_shards(leaf, (spec, spec), spec, x, y.astype(x.dtype))
 
 

@@ -12,7 +12,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,13 +71,30 @@ def _compile(fn, *args):
 def test_fedadc_update_compiles(one_chip, kernel, dtype):
     leaf = _sds((ROWS, ops.LANE), dtype, one_chip)
     fns = {
-        "fused_axpy": lambda x, y: _fu.fused_axpy_2d(x, y, -0.05),
+        "fused_axpy": lambda x, y: _fu.fused_axpy(x, y, -0.05),
         "local_update": lambda t, g, m: _fu.local_update_2d(t, g, m, 0.05),
         "server_update": lambda t, m, d: _fu.server_update_2d(
             t, m, d, 0.1, 0.05),
     }
     n_in = 2 if kernel == "fused_axpy" else 3
     _compile(fns[kernel], *([leaf] * n_in))
+
+
+def test_fused_axpy_keeps_leaf_layout(one_chip, mosaic):
+    """The local SGD step on the stacked MLP leaf as the round holds it:
+    the kernel takes the leaf in its own (8, 128)-tiled layout, so no
+    copy or reshape of the leaf's size sits on either side of it."""
+    shape = (3, D_MODEL, D_FF)
+    leaf = _sds(shape, jnp.float32, one_chip)
+    hlo = _compile(lambda x, y: ops.fused_axpy(x, y, -0.05), leaf,
+                   leaf).as_text()
+    n = math.prod(shape)
+    relayouts = [
+        name for name, dims in re.findall(
+            r"%([\w.-]+) = \w+\[([\d,]*)\]", hlo)
+        if ("copy" in name or "reshape" in name)
+        and math.prod(int(d) for d in dims.split(",") if d) == n]
+    assert not relayouts, relayouts
 
 
 @pytest.mark.parametrize("dtype", DTYPES)

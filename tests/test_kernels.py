@@ -141,12 +141,39 @@ def test_property_server_update(n, eta, gamma):
     np.testing.assert_allclose(m2["p"], me, atol=1e-5, rtol=1e-4)
 
 
-def test_fused_axpy_pytree_shapes():
-    theta = {"a": jnp.ones((7, 13)), "b": jnp.arange(5, dtype=jnp.float32)}
-    y = jax.tree.map(lambda x: x * 2.0, theta)
-    out = jax.tree.map(lambda a, b: ops.fused_axpy(a, b, -0.5), theta, y)
-    for leaf in jax.tree.leaves(out):
-        np.testing.assert_allclose(leaf, jnp.zeros_like(leaf))
+def _bits(x):
+    return np.asarray(jax.lax.bitcast_convert_type(
+        x, jnp.uint16 if x.dtype == jnp.bfloat16 else jnp.uint32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("shape", [(3, 256, 384), (3, 128), (2560,), (7, 13),
+                                   (5,), (2, 3, 3, 40)])
+def test_fused_axpy_pytree_shapes(shape, dtype):
+    """The axpy runs on each leaf in its own shape; x + a·y is element by
+    element, so it is bitwise the compiled x + a·y (compiled too: the
+    compiler may contract a·y + x into one multiply-add)."""
+    ks = jax.random.split(jax.random.PRNGKey(8), 2)
+    x, y = rand(ks[0], shape, dtype), rand(ks[1], shape, dtype)
+    out = ops.fused_axpy(x, y, -0.05)
+    assert out.shape == shape and out.dtype == dtype
+    expect = jax.jit(lambda x, y: x + -0.05 * y)(x, y)
+    np.testing.assert_array_equal(_bits(out), _bits(expect))
+    zeros = ops.tree_fused_axpy({"p": x}, {"p": x * 2.0}, -0.5)["p"]
+    np.testing.assert_array_equal(zeros, jnp.zeros_like(x))
+
+
+def test_tree_fused_axpy_keeps_leaf_layout():
+    """An aligned stacked leaf reaches the kernel as it is: no flatten,
+    pad, slice or re-tiling around the call, each of which is an HBM
+    copy on the TPU."""
+    leaf = jax.ShapeDtypeStruct((3, 256, 384), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda x, y: ops.tree_fused_axpy(
+        {"w": x}, {"w": y}, -0.05))(leaf, leaf)
+    prims = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+    assert "pallas_call" in prims
+    assert not prims & {"reshape", "pad", "slice", "dynamic_slice",
+                        "concatenate"}, prims
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,10 @@
-"""qwen3-14b [dense] — qk_norm + GQA [hf:Qwen/Qwen3-8B family]."""
+"""qwen3-14b [dense] — qk_norm + GQA, untied head [hf:Qwen/Qwen3-14B]."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
     arch_id="qwen3-14b",
     family="dense",
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-14B",
     n_layers=40,
     d_model=5120,
     n_heads=40,
@@ -14,5 +14,7 @@ CONFIG = ModelConfig(
     head_dim=128,
     qk_norm=True,
     rope_theta=1_000_000.0,
-    max_seq_len=131_072,
+    norm_eps=1e-6,
+    tie_embeddings=False,
+    max_seq_len=40_960,
 )

@@ -247,7 +247,8 @@ def sparse_weighted_delta_reduce(values, indices, weights, shape, dtype):
 # attention / ssd / kd
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal=True, window=0, block_q=128, block_k=128):
+def flash_attention(q, k, v, causal=True, window=0, block_q=None,
+                    block_k=None):
     """q (B,L,H,D) model layout -> (B,L,H,D).
 
     Differentiable: the forward pass is the Pallas kernel, the backward

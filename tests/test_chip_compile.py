@@ -15,6 +15,8 @@ this file.
 import math
 import os
 import re
+import types
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +29,7 @@ from repro.kernels import fedadc_update as _fu
 from repro.kernels import ops
 from repro.kernels import weighted_reduce as _wr
 
+CHIP_DIR = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
 D_MODEL, D_FF = 2560, 9728
 ROWS = D_MODEL * D_FF // ops.LANE          # the MLP leaf as (rows, 128) tiles
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -127,19 +130,84 @@ def test_flash_attention_forward_compiles(one_chip, mosaic):
              *_qkv(one_chip))
 
 
+def _fwd_bwd(q, k, v, g):
+    out, pullback = jax.vjp(
+        lambda *a: ops.flash_attention(*a, causal=True), q, k, v)
+    return out, pullback(g)
+
+
 def test_flash_attention_backward_compiles(one_chip, mosaic):
     """The custom VJP as a training step runs it: the kernel forward and
     the float32-oracle backward in one program."""
     q, k, v = _qkv(one_chip)
-
-    def fwd_bwd(q, k, v, g):
-        out, pullback = jax.vjp(
-            lambda *a: ops.flash_attention(*a, causal=True), q, k, v)
-        return out, pullback(g)
-    compiled = _compile(fwd_bwd, q, k, v, q)
+    compiled = _compile(_fwd_bwd, q, k, v, q)
     out, grads = compiled.out_info
     assert out.shape == q.shape
     assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+
+
+# The benchmark cells' attention as each device sees it, fp32, head dim
+# 128: (batch, L, q heads, kv heads).  The 14B shard is one device's part
+# of the (2, 2) mesh: half the batch over "data", half the heads over
+# "model", so a GQA group of 5.
+CELL_ATTENTION = {
+    "qwen3-4b.silo": (4, 1024, 32, 8),
+    "qwen3-4b.cohort": (1, 512, 32, 8),
+    "qwen3-14b.shard": (2, 1024, 20, 4),
+}
+
+
+def _cell_qkv(one_chip, cell):
+    B, L, H, Hk = CELL_ATTENTION[cell]
+    return (_sds((B, L, H, 128), jnp.float32, one_chip),
+            _sds((B, L, Hk, 128), jnp.float32, one_chip),
+            _sds((B, L, Hk, 128), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("cell", CELL_ATTENTION)
+def test_flash_attention_cell_shapes_compile(one_chip, mosaic, cell):
+    """At each cell's shapes the kernel's own block choice fits the
+    chip's VMEM, forward and under the VJP a training step takes."""
+    q, k, v = _cell_qkv(one_chip, cell)
+    _compile(lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
+             q, k, v)
+    out, grads = _compile(_fwd_bwd, q, k, v, q).out_info
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+
+
+@pytest.fixture
+def bench(monkeypatch):
+    """The benchmark's own trace reader and flash-attention reader."""
+    monkeypatch.syspath_prepend(str(CHIP_DIR))
+    from bench import trace
+    from bench.registry import Registry
+    registry = Registry(CHIP_DIR)
+    return (trace, registry.flops("flash_attention"),
+            registry.metric("flash_attention_roofline"),
+            registry.peaks("TPU v5 lite"))
+
+
+@pytest.mark.parametrize("cell", CELL_ATTENTION)
+def test_flash_attention_meets_its_reader(one_chip, mosaic, bench, cell):
+    """`flash_attention_roofline` finds the kernel's calls by the name
+    ``_flash_kernel`` and counts from its first two operands, which it
+    takes to be q (B·H, L, D) and k (B·Hk, L, D).  A call that lasts its
+    least time at the true shapes must read 100%."""
+    trace, fl, reader, peak = bench
+    B, L, H, Hk = CELL_ATTENTION[cell]
+    hlo = _compile(lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
+                   *_cell_qkv(one_chip, cell)).as_text()
+    kernels = trace.hlo_kernels(hlo)
+    assert [k["kernel"] for k in kernels.values()] == ["_flash_kernel"]
+    (op, info), = kernels.items()
+    assert info["operands"][:2] == [["f32", [B * H, L, 128]],
+                                    ["f32", [B * Hk, L, 128]]]
+    least = fl.least_time_s(B * H, B * Hk, L, 128, 4, peak)
+    ns = round(least * 1e9)
+    tr = {"window": [0, 2 * ns], "host": [], "kernels": kernels,
+          "devices": {0: {"ops": [[op, 0, ns]], "async": []}}}
+    ctx = types.SimpleNamespace(trace=tr, peak=peak, flops=lambda _: fl)
+    assert reader.read(ctx) == pytest.approx(100.0, rel=1e-5)
 
 
 @pytest.mark.xfail(

@@ -25,6 +25,8 @@ def rand(key, shape, dtype):
     (2, 4, 2, 256, 64),     # GQA group 2
     (1, 8, 1, 128, 128),    # MQA
     (1, 4, 4, 192, 64),     # L not multiple of block
+    (1, 8, 2, 512, 64),     # GQA group 4, L 512
+    (1, 5, 1, 1024, 64),    # GQA group 5 (a 14B shard's), L 1024
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_shapes(B, H, Hk, L, D, dtype):
@@ -39,6 +41,60 @@ def test_flash_attention_shapes(B, H, Hk, L, D, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32),
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("B,H,Hk,L,D", [
+    (1, 4, 1, 1024, 128),   # group 4: 4 q blocks, causal within one k block
+    (1, 5, 1, 1024, 128),   # group 5: rows not a power of two
+    (2, 8, 2, 512, 64),     # one k block
+    (1, 4, 4, 192, 64),     # L under one block
+    (1, 8, 1, 128, 128),    # MQA
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_chosen_blocks(B, H, Hk, L, D, dtype):
+    """The blocks the kernel picks from the shapes, against the oracle."""
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = rand(ks[0], (B, H, L, D), dtype)
+    k = rand(ks[1], (B, Hk, L, D), dtype)
+    v = rand(ks[2], (B, Hk, L, D), dtype)
+    out = FA.flash_attention(q, k, v, causal=True, interpret=True)
+    expect = ref.flash_attention(q, k, v, causal=True)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(expect, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("L,block_q,block_k,causal,window", [
+    (1024, 256, 512, True, 0),
+    (1024, 128, 128, True, 0),
+    (1024, 512, 256, True, 0),
+    (192, 64, 128, True, 0),     # ragged last k block
+    (256, 64, 64, True, 32),
+    (256, 128, 64, True, 100),
+    (512, 128, 128, False, 0),
+])
+def test_flash_band_is_the_visible_blocks(L, block_q, block_k, causal,
+                                          window):
+    """A q block computes, and fetches K/V for, exactly the k blocks that
+    hold a (query, key) pair its mask lets through; every other step keeps
+    the index of a visible block, so the pipeline fetches nothing new."""
+    n_kb = -(-L // block_k)
+    for qi in range(-(-L // block_q)):
+        lo, hi = FA._band(qi, block_q=block_q, block_k=block_k, n_kb=n_kb,
+                          causal=causal, window=window)
+        qpos = np.arange(qi * block_q, min((qi + 1) * block_q, L))[:, None]
+        kpos = np.arange(L)[None, :]
+        seen = np.ones((len(qpos), L), bool)
+        if causal:
+            seen &= kpos <= qpos
+        if window > 0:
+            seen &= kpos > qpos - window
+        visible = sorted({int(c) // block_k for c in np.nonzero(seen)[1]})
+        assert visible == list(range(int(lo), int(hi) + 1))
+        fetched = [int(np.clip(ki, lo, hi)) for ki in range(n_kb)]
+        assert sorted(set(fetched)) == visible
+        assert fetched == sorted(fetched)   # no block fetched twice
 
 
 @pytest.mark.parametrize("window", [32, 64, 128])

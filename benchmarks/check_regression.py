@@ -2,9 +2,9 @@
 
 Compares a freshly emitted ``BENCH_*.json`` against the committed copy:
 every numeric field reachable at the same path must agree within a relative
-tolerance (default 20%), and booleans/strings must match exactly.  Wall-
-clock-derived fields (runner-speed dependent) are skipped by key pattern so
-the gate checks *what* the benchmark measured, not how fast the runner was.
+tolerance (default 20%), and booleans/strings must match exactly.  The
+records hold counts and correctness flags only: a time is measured on the
+chip (``benchmarks/chip``), never on a CI runner.
 
 The tolerance is relative with an absolute floor (``--atol``): derived
 difference-of-large-numbers fields (e.g. an accuracy *gap* of 0.0017) must
@@ -19,8 +19,7 @@ refactor promised (a fresh file that silently stopped emitting them would
 otherwise still pass).  Everything *under* a required path is additionally
 checked to be well-formed — numeric leaves must be finite (a drift metric
 that collapsed to NaN/inf is a regression even though NaN != NaN would
-slip through an equality diff) — while wall-clock keys inside the section
-stay skipped.
+slip through an equality diff).
 
 Usage:  python benchmarks/check_regression.py fresh.json:committed.json \\
             [--tol 0.2] [--atol 0.01] [--require path ...]
@@ -31,12 +30,7 @@ from __future__ import annotations
 import argparse
 import json
 import math
-import re
 import sys
-
-# runner-speed dependent fields; excluded from the gate
-SKIP_KEY = re.compile(
-    r"(wall|latency|per_s|per_round|per_tok|us_|_us|speedup|time)", re.I)
 
 
 def _walk(fresh, committed, path, tol, atol, errors):
@@ -45,8 +39,6 @@ def _walk(fresh, committed, path, tol, atol, errors):
             errors.append(f"{path}: type changed ({type(fresh).__name__})")
             return
         for k, cv in committed.items():
-            if SKIP_KEY.search(str(k)):
-                continue
             if k not in fresh:
                 errors.append(f"{path}.{k}: missing from fresh output")
                 continue
@@ -76,12 +68,9 @@ def _walk(fresh, committed, path, tol, atol, errors):
 
 
 def _check_finite(node, path, errors):
-    """Numeric leaves under a required section must be finite; wall-clock
-    keys are skipped exactly as in the committed-driven walk."""
+    """Numeric leaves under a required section must be finite."""
     if isinstance(node, dict):
         for k, v in node.items():
-            if SKIP_KEY.search(str(k)):
-                continue
             _check_finite(v, f"{path}.{k}", errors)
     elif isinstance(node, list):
         for i, v in enumerate(node):

@@ -98,7 +98,6 @@ def _cell(name_kv, r):
         "downlink_bytes": int(s.downlink_bytes),
         "downlink_bytes_raw": int(s.downlink_bytes_raw),
         "bytes_reduction": round(s.uplink_bytes_raw / s.uplink_bytes, 2),
-        "us_per_round": r["us_per_round"],
     })
     return cell
 
@@ -204,7 +203,6 @@ def intermittent_sweep(rounds=40, n_clients=20, seed=0):
             "catchups": n_catchup, "resyncs": n_resync,
             "catchup_bytes": int(n_catchup * t._down_nbytes),
             "resync_bytes": int(n_resync * t._down_raw),
-            "us_per_round": r["us_per_round"],
         })
     return cells
 
@@ -216,8 +214,7 @@ def main(rows=None, rounds=90, async_rounds=80, intermittent_rounds=40,
     by = {(c["strategy"], c["compressor"]): c for c in cells}
     for c in cells:
         rows.append(emit(
-            f"comm_sweep.{c['strategy']}.{c['compressor']}",
-            c["us_per_round"],
+            f"comm_sweep.{c['strategy']}.{c['compressor']}", 0,
             f"acc={c['acc']};up_MB={c['uplink_bytes']/2**20:.2f};"
             f"down_MB={c['downlink_bytes']/2**20:.2f};"
             f"reduction={c['bytes_reduction']:.2f}x"))
@@ -225,8 +222,7 @@ def main(rows=None, rounds=90, async_rounds=80, intermittent_rounds=40,
     drift.update(async_drift)
     for c in async_cells:
         rows.append(emit(
-            f"comm_sweep.async.fedadc.{c['compressor']}.{c['staleness']}",
-            c["us_per_round"],
+            f"comm_sweep.async.fedadc.{c['compressor']}.{c['staleness']}", 0,
             f"acc={c['acc']};up_MB={c['uplink_bytes']/2**20:.2f};"
             f"stale={c['mean_staleness']:.2f};"
             f"reduction={c['bytes_reduction']:.2f}x"))
@@ -234,14 +230,13 @@ def main(rows=None, rounds=90, async_rounds=80, intermittent_rounds=40,
     for c in intermittent_cells:
         rows.append(emit(
             f"comm_sweep.intermittent.av{c['availability']}"
-            f".h{c['resync_horizon']}", c["us_per_round"],
+            f".h{c['resync_horizon']}", 0,
             f"acc={c['acc']};down_MB={c['downlink_bytes']/2**20:.2f};"
             f"catchups={c['catchups']};resyncs={c['resyncs']}"))
     downlink_cells = downlink_sweep(by[("fedadc", "none")], rounds=rounds)
     for c in downlink_cells:
         rows.append(emit(
-            f"comm_sweep.downlink.fedadc.{c['downlink']}",
-            c["us_per_round"],
+            f"comm_sweep.downlink.fedadc.{c['downlink']}", 0,
             f"acc={c['acc']};down_MB={c['downlink_bytes']/2**20:.2f};"
             f"down_vs_up_raw={c['downlink_vs_uplink_raw']:.3f}x"))
     d = drift["fedadc_none"]

@@ -8,14 +8,11 @@ Prints ``name,us_per_call,derived`` CSV.  Modules:
   fig5_scale         — Fig. 5/6    (low participation, many clients)
   fig7_personalization — Fig. 7    (classifier calibration, 3 regularisers)
   clustering         — Sec. IV-E   (class-coverage client selection)
-  kernels_bench      — Pallas kernels µs/call + derived bytes/flops
-  roofline_report    — §Roofline terms per (arch × shape × mesh) from the
-                       dry-run artifacts
   straggler_bench    — wall-clock-to-accuracy, sync vs semi-async FedADC
                        under a 4× straggler fleet (DESIGN.md §Heterogeneity)
-  serving_bench      — continuous batching vs serial decode: offered-load
-                       sweep, tokens/sec + p50/p95 latency
-                       (DESIGN.md §Serving; emits BENCH_serving.json)
+  serving_bench      — continuous batching vs serial decode: step counts
+                       and greedy identity (DESIGN.md §Serving; emits
+                       BENCH_serving_smoke.json)
   comm_load          — Sec. II-A   analytic bytes/round per strategy, side by
                        side with measured per-client wire bytes through each
                        compressor (DESIGN.md §Compression)
@@ -23,11 +20,11 @@ Prints ``name,us_per_call,derived`` CSV.  Modules:
                        compressor on the non-IID benchmark (emits
                        BENCH_comm.json)
   telemetry_bench    — telemetry-enabled vs disabled sync rounds: the
-                       DESIGN.md §Telemetry ≤5% overhead contract,
-                       measured (emits BENCH_telemetry.json)
+                       enabled run leaves the accuracy unchanged (emits
+                       BENCH_telemetry.json)
   fleet_bench        — flat vs two-tier hierarchical aggregation at
-                       K ∈ {1e3,1e4,1e5} simulated clients: rounds/s +
-                       peak host bytes with a paged, budget-bounded
+                       K ∈ {1e3,1e4,1e5} simulated clients: peak host
+                       bytes and page traffic with a paged, budget-bounded
                        client store (DESIGN.md §Fleet; emits
                        BENCH_fleet.json)
 """
@@ -45,14 +42,12 @@ def main() -> None:
 
     from benchmarks import (ablation_beta, clustering, comm_load, comm_sweep,
                             fig1_acceleration, fig2_robustness, fig5_scale,
-                            fig7_personalization, fleet_bench, kernels_bench,
-                            lm_round, roofline_report, serving_bench,
-                            straggler_bench, table1_sota, telemetry_bench)
+                            fig7_personalization, fleet_bench, lm_round,
+                            serving_bench, straggler_bench, table1_sota,
+                            telemetry_bench)
     mods = {
-        "kernels_bench": kernels_bench,
         "comm_load": comm_load,
         "comm_sweep": comm_sweep,
-        "roofline_report": roofline_report,
         "fig1_acceleration": fig1_acceleration,
         "fig2_robustness": fig2_robustness,
         "table1_sota": table1_sota,

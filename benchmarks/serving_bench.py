@@ -1,25 +1,17 @@
 """Serving bench: continuous batching vs serial one-at-a-time decode.
 
-Offered-load sweep: the same request set (random prompt lengths, fixed
-generation budget) is pushed through the ServingEngine at increasing slot
-counts (concurrency = offered load, closed-loop: every request is queued
-at t=0 and waits for a slot).  Reported per level: generated tokens/sec
-and p50/p95 end-to-end request latency.  ``n_slots=1`` IS the serial
-baseline — one request at a time through the identical prefill-chunk +
-decode-step path — so the speedup column isolates the scheduler/batching
-win from kernel effects.
-
-Emits ``BENCH_serving.json`` and the repo-standard ``name,us_per_call,
-derived`` CSV rows (middle column = wall-µs per generated token).
-
-``--smoke`` runs the CI job: 8 requests through a 4-slot scheduler and
-asserts greedy outputs are identical to the serial engine.
+8 requests (random prompt lengths, fixed generation budget) go through the
+ServingEngine at 4 slots and at 1 slot, the serial baseline: one request
+at a time through the identical prefill-chunk + decode-step path.  Greedy
+outputs must be identical; the engine-step counts of the two runs show
+the scheduler's batching.  Emits ``BENCH_serving_smoke.json`` (counts, no
+timings) for the CI bench-smoke gate, and one ``name,us_per_call,derived``
+row under ``benchmarks/run.py``.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import time
 
 import jax
 import numpy as np
@@ -27,7 +19,7 @@ import numpy as np
 from benchmarks.common import emit
 from repro.configs.base import ModelConfig
 from repro.models.registry import get_model
-from repro.serving import SchedulerConfig, ServingEngine, latency_summary
+from repro.serving import SchedulerConfig, ServingEngine
 
 TINY = ModelConfig(arch_id="serving-bench-tiny", n_layers=2, d_model=128,
                    n_heads=4, n_kv_heads=4, d_ff=256, vocab_size=512,
@@ -46,32 +38,21 @@ def run_level(params, prompts, n_slots, prefill_chunk=16):
     eng = ServingEngine(TINY, params=params, sched=SchedulerConfig(
         n_slots=n_slots, max_len=MAX_LEN, prefill_chunk=prefill_chunk,
         page_size=32))
-    t0 = time.perf_counter()
     for p in prompts:
         eng.add_request(p, max_new_tokens=GEN)
     outs = eng.run()
-    # barrier on the device-resident KV cache before stopping the clock
-    jax.block_until_ready(eng.cachemgr.cache)
-    wall = time.perf_counter() - t0
-    tokens = sum(len(o.tokens) for o in outs)
     return {
         "n_slots": n_slots,
         "n_requests": len(prompts),
-        "gen_tokens": tokens,
-        "wall_s": round(wall, 3),
-        "tokens_per_s": round(tokens / wall, 1),
-        # TTFT/ITL/e2e percentiles from the shared telemetry helper — the
-        # same summary the engine's telemetry `summary` event reports
-        "latency": latency_summary(outs),
+        "gen_tokens": sum(len(o.tokens) for o in outs),
         "engine_steps": eng.n_steps,
     }, outs
 
 
-def smoke(out_json="BENCH_serving_smoke.json"):
-    """CI job: 8 requests through the 4-slot scheduler, greedy outputs
-    bit-identical to the serial engine.  Emits a JSON of the deterministic
-    counters (token/step counts, not wall-clock) so the bench-smoke gate
-    can diff it against the committed copy."""
+def main(rows=None, out_json="BENCH_serving_smoke.json"):
+    """8 requests through the 4-slot scheduler, greedy outputs
+    bit-identical to the serial engine."""
+    rows = rows if rows is not None else []
     model = get_model(TINY)
     params = model.init(jax.random.PRNGKey(0), TINY)
     prompts = make_requests(8)
@@ -89,54 +70,17 @@ def smoke(out_json="BENCH_serving_smoke.json"):
     with open(out_json, "w") as f:
         json.dump(report, f, indent=2)
     print(f"# wrote {out_json}")
-    print(f"serving smoke OK: {len(prompts)} requests, "
-          f"{sum(len(o.tokens) for o in batched)} tokens, "
-          f"batched == serial")
-
-
-def main(rows=None, n_requests=16, levels=(1, 2, 4, 8),
-         out_json="BENCH_serving.json"):
-    rows = rows if rows is not None else []
-    model = get_model(TINY)
-    params = model.init(jax.random.PRNGKey(0), TINY)
-    prompts = make_requests(n_requests)
-    results = []
-    for n_slots in levels:
-        run_level(params, prompts[:2], n_slots)      # warmup/compile
-        res, _ = run_level(params, prompts, n_slots)
-        results.append(res)
-        us_per_tok = res["wall_s"] / res["gen_tokens"] * 1e6
-        lat = res["latency"]
-        rows.append(emit(f"serving.slots{n_slots}.tokens_per_s", us_per_tok,
-                         res["tokens_per_s"]))
-        rows.append(emit(f"serving.slots{n_slots}.p50_p95_s", us_per_tok,
-                         f"{lat['e2e_s']['p50']}/{lat['e2e_s']['p95']}"))
-        rows.append(emit(f"serving.slots{n_slots}.ttft_itl_p50_s", us_per_tok,
-                         f"{lat['ttft_s']['p50']}/{lat['itl_s']['p50']}"))
-    base = results[0]["tokens_per_s"]
-    peak = results[-1]["tokens_per_s"]
-    speedup = peak / base
-    rows.append(emit("serving.batch_vs_serial_speedup", 0,
-                     f"{speedup:.2f}x"))
-    report = {"model": TINY.arch_id, "max_len": MAX_LEN, "gen": GEN,
-              "levels": results, "speedup_vs_serial": round(speedup, 2)}
-    with open(out_json, "w") as f:
-        json.dump(report, f, indent=2)
-    print(f"# wrote {out_json}")
+    rows.append(emit("serving.batched_equals_serial", 0,
+                     f"steps_batched={res_b['engine_steps']};"
+                     f"steps_serial={res_s['engine_steps']}"))
     return rows
 
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
-    ap.add_argument("--smoke", action="store_true",
-                    help="CI: 8 requests through the scheduler + identity "
-                         "check vs serial")
-    ap.add_argument("--requests", type=int, default=16)
-    ap.add_argument("--out", default=None,
-                    help="JSON output path (default depends on mode)")
+    ap.add_argument("--smoke", action="store_true", required=True,
+                    help="8 requests through the scheduler + identity "
+                         "check vs serial (the bench's one run)")
+    ap.add_argument("--out", default="BENCH_serving_smoke.json")
     args = ap.parse_args()
-    if args.smoke:
-        smoke(out_json=args.out or "BENCH_serving_smoke.json")
-    else:
-        main(n_requests=args.requests,
-             out_json=args.out or "BENCH_serving.json")
+    main(out_json=args.out)

@@ -1,71 +1,45 @@
-"""Telemetry overhead bench: the §Telemetry ≤5% contract, measured.
+"""Telemetry bench: the enabled path leaves the numerics alone.
 
 Runs the same synchronous FedADC configuration twice — telemetry disabled
 (the default) and enabled with in-jit drift diagnostics + span tracing —
-and compares wall-clock per round after a shared warmup.  The enabled run
-pays exactly one extra host fetch per round (the metric scalar tree) and a
-handful of in-jit reductions; the bench asserts the measured overhead
-stays within the documented 5% budget and emits ``BENCH_telemetry.json``
-for the CI bench-smoke gate (``overhead_le_5pct`` is the committed
-boolean; the raw ratio rides a wall-clock-named key the regression walk
-skips).
+and checks that both produce the identical final accuracy: the
+observability path is not allowed to touch the numerics.  Emits
+``BENCH_telemetry.json`` for the CI bench-smoke gate
+(``enabled_acc_identical`` is the committed boolean).
 
-Also sanity-checks the contract's other half while it is at it: the
-enabled and disabled runs must produce identical final accuracy — the
-observability path is not allowed to touch the numerics.
+What the enabled path costs is a time, and so is left to a run on the
+chip (DESIGN.md §Telemetry).
 """
 from __future__ import annotations
 
 import argparse
 import json
-import time
-
-import jax
 
 from benchmarks.common import dataset, emit, partitions, run_fl
 from repro.telemetry import Telemetry
 
-MAX_OVERHEAD = 0.05
+
+def _run(parts, data, rounds, telemetry):
+    return run_fl("fedadc", parts, data, rounds=rounds, n_clients=20, seed=0,
+                  telemetry=Telemetry(engine="sim") if telemetry else None)
 
 
-def _timed_run(parts, data, rounds, warmup, telemetry):
-    # one throwaway run compiles the round/eval functions for this config
-    # (jit caches are keyed on the traced program, which differs between
-    # the metric and no-metric round functions)
-    run_fl("fedadc", parts, data, rounds=warmup, n_clients=20, seed=0,
-           telemetry=Telemetry(engine="sim") if telemetry else None)
-    t0 = time.perf_counter()
-    r = run_fl("fedadc", parts, data, rounds=rounds, n_clients=20, seed=0,
-               telemetry=Telemetry(engine="sim") if telemetry else None)
-    jax.block_until_ready(r["sim"].params)  # barrier before stopping the clock
-    return time.perf_counter() - t0, r
-
-
-def main(rows=None, rounds=40, warmup=4, out_json="BENCH_telemetry.json"):
+def main(rows=None, rounds=40, out_json="BENCH_telemetry.json"):
     rows = rows if rows is not None else []
     data = dataset()
     parts = partitions(data[1], 20, "sort", 2, seed=0)
-    wall_off, r_off = _timed_run(parts, data, rounds, warmup, False)
-    wall_on, r_on = _timed_run(parts, data, rounds, warmup, True)
-    ratio = wall_on / wall_off
-    overhead = ratio - 1.0
-    rows.append(emit("telemetry.sync_round_overhead",
-                     wall_on / rounds * 1e6, f"{overhead:+.2%}"))
+    r_off = _run(parts, data, rounds, False)
+    r_on = _run(parts, data, rounds, True)
     identical = bool(r_on["acc"] == r_off["acc"])
     rows.append(emit("telemetry.enabled_acc_identical", 0, identical))
     report = {
         "rounds": rounds,
-        "wall_ratio_on_vs_off": round(ratio, 4),
-        "overhead_le_5pct": bool(overhead <= MAX_OVERHEAD),
         "enabled_acc_identical": identical,
     }
     with open(out_json, "w") as f:
         json.dump(report, f, indent=2)
     print(f"# wrote {out_json}")
     assert identical, "telemetry-enabled run changed the accuracy"
-    assert overhead <= MAX_OVERHEAD, (
-        f"telemetry overhead {overhead:+.2%} exceeds the documented "
-        f"{MAX_OVERHEAD:.0%} budget")
     return rows
 
 

@@ -95,45 +95,6 @@ def tree_fused_axpy(xs, ys, a):
         xs, ys)
 
 
-def fedadc_local_update(theta, g, m_bar, eta):
-    """θ − η(g + m̄) over a whole pytree."""
-    def leaf(t, gi, mi):
-        tt, pad = _as_tiles(t)
-        gt, _ = _as_tiles(gi)
-        mt, _ = _as_tiles(mi)
-        out = _fu.local_update_2d(tt, gt, mt, eta, interpret=_interpret())
-        return _from_tiles(out, pad, t.shape, t.dtype)
-
-    def apply(path, t, gi, mi):
-        spec = leaf_spec(path, t.shape)
-        return _on_shards(leaf, (spec,) * 3, spec, t, gi.astype(t.dtype),
-                          mi.astype(t.dtype))
-    return jax.tree_util.tree_map_with_path(apply, theta, g, m_bar)
-
-
-def fedadc_server_update(theta, m, delta_bar, gamma, alpha_eta):
-    """(θ', m') fused server update over a whole pytree."""
-    def leaf(t, mi, di):
-        tt, pad = _as_tiles(t)
-        mt, _ = _as_tiles(mi)
-        dt, _ = _as_tiles(di)
-        to, mo = _fu.server_update_2d(tt, mt, dt, gamma, alpha_eta,
-                                      interpret=_interpret())
-        return (_from_tiles(to, pad, t.shape, t.dtype),
-                _from_tiles(mo, pad, t.shape, t.dtype))
-
-    def apply(path, t, mi, di):
-        spec = leaf_spec(path, t.shape)
-        return _on_shards(leaf, (spec,) * 3, (spec, spec), t,
-                          mi.astype(t.dtype), di.astype(t.dtype))
-    pairs = jax.tree_util.tree_map_with_path(apply, theta, m, delta_bar)
-    theta_new = jax.tree.map(lambda p: p[0], pairs,
-                             is_leaf=lambda x: isinstance(x, tuple))
-    m_new = jax.tree.map(lambda p: p[1], pairs,
-                         is_leaf=lambda x: isinstance(x, tuple))
-    return theta_new, m_new
-
-
 def weighted_delta_reduce(stacked, weights):
     """Σ_k w_k·Δ_k over a stacked pytree (leading axis K on every leaf).
     Weights are applied as given (normalise upstream for a weighted mean)."""

@@ -17,17 +17,6 @@ def fused_axpy(x, y, a):
     return x + a * y
 
 
-def fedadc_local_update(theta, g, m_bar, eta):
-    """Heavy-ball embedded step (Alg. 3 blue): θ − η(g + m̄)."""
-    return theta - eta * (g + m_bar)
-
-
-def fedadc_server_update(theta, m, delta_bar, gamma, alpha_eta):
-    """Alg. 3 lines 17+19: m' = Δ̄ + γ·m ; θ' = θ − αη·m'.  -> (θ', m')."""
-    m_new = delta_bar + gamma * m
-    return theta - alpha_eta * m_new, m_new
-
-
 def weighted_delta_reduce(deltas, weights):
     """Σ_k w_k·Δ_k for a single stacked array (K, ...); accumulates in at
     least fp32 (bf16 partial sums drown the late terms as K grows; f64

@@ -1,29 +1,29 @@
-"""Roofline term extraction from compiled dry-run artifacts.
+"""Counts from a compiled dry-run program: FLOPs, bytes, collectives.
 
-Three terms per (arch × shape × mesh), in seconds:
+Per (arch × shape × mesh) the compiled program gives per-device counts:
 
-  compute    = HLO_FLOPs            / (chips × 197e12  bf16 FLOP/s)
-  memory     = HLO_bytes_accessed   / (chips × 819e9   B/s HBM)
-  collective = wire_bytes           / (chips × 50e9    B/s ICI per link)
+  flops, bytes      ``cost_analysis`` (a partitioned module is the
+                    per-device program, so both are per chip)
+  wire_bytes        collective traffic per device, parsed from the
+                    optimized HLO
+  per_device_hbm    ``memory_analysis``: arguments, outputs, temporaries
+                    and generated code
 
-``cost_analysis`` provides FLOPs / bytes.  Collective bytes are NOT in
-cost_analysis: we parse the optimized HLO and, for every all-gather /
-all-reduce / reduce-scatter / all-to-all / collective-permute, take the
-result shapes and apply the ring-transfer factor for its replica-group size
-g (all-reduce 2(g−1)/g, gather/scatter/a2a (g−1)/g, permute 1).  wire_bytes
-is per-device traffic: result shapes in partitioned HLO are already
+Collective bytes are NOT in cost_analysis: we parse the optimized HLO and,
+for every all-gather / all-reduce / reduce-scatter / all-to-all /
+collective-permute, take the result shapes and apply the ring-transfer
+factor for its replica-group size g (all-reduce 2(g−1)/g, gather/scatter/
+a2a (g−1)/g, permute 1).  Result shapes in partitioned HLO are already
 per-shard.
+
+These are counts, not times: how long a program takes on the chip comes
+only from a run there (``benchmarks/chip``).
 """
 from __future__ import annotations
 
 import re
 from dataclasses import dataclass
-from typing import Dict, List
-
-# TPU v5e-ish hardware constants (assignment-specified)
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+from typing import Dict
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -112,50 +112,20 @@ class Roofline:
     chips: int
     collectives: CollectiveStats
     per_device_hbm: float          # from memory_analysis
-    model_flops_per_chip: float = 0.0
-
-    @property
-    def compute_s(self):
-        # XLA's cost_analysis counts while-loop (scan) bodies ONCE, so the
-        # HLO count underestimates layer/step-scanned programs; the analytic
-        # 6·N·D term is the floor.  Take the max of the two estimates.
-        return max(self.flops, self.model_flops_per_chip) / PEAK_FLOPS
-
-    @property
-    def compute_s_hlo(self):
-        return self.flops / PEAK_FLOPS
-
-    @property
-    def memory_s(self):
-        return self.bytes_accessed / HBM_BW
-
-    @property
-    def collective_s(self):
-        return self.wire_bytes / ICI_BW
-
-    @property
-    def dominant(self):
-        terms = {"compute": self.compute_s, "memory": self.memory_s,
-                 "collective": self.collective_s}
-        return max(terms, key=terms.get)
 
     def as_dict(self):
         return {
             "flops": self.flops, "bytes": self.bytes_accessed,
             "wire_bytes": self.wire_bytes,
-            "compute_s": self.compute_s,
-            "compute_s_hlo": self.compute_s_hlo,
-            "memory_s": self.memory_s,
-            "collective_s": self.collective_s, "dominant": self.dominant,
             "per_device_hbm_gb": self.per_device_hbm / 2**30,
             "collective_counts": self.collectives.counts,
         }
 
 
-def analyze(compiled, mesh, model_flops_per_chip: float = 0.0) -> Roofline:
+def analyze(compiled, mesh) -> Roofline:
     """compiled: jax Compiled object.  Costs reported by XLA for a
     partitioned module are per-device (the module IS the per-device
-    program), so terms are already per-chip."""
+    program), so counts are already per-chip."""
     chips = mesh.devices.size
     ca = compiled.cost_analysis()
     if isinstance(ca, list):
@@ -175,8 +145,7 @@ def analyze(compiled, mesh, model_flops_per_chip: float = 0.0) -> Roofline:
     # arguments+outputs alias for donated state; report args+temps
     return Roofline(flops=flops, bytes_accessed=nbytes,
                     wire_bytes=coll.wire_bytes, chips=chips,
-                    collectives=coll, per_device_hbm=hbm,
-                    model_flops_per_chip=model_flops_per_chip)
+                    collectives=coll, per_device_hbm=hbm)
 
 
 def model_flops_per_round(mcfg, shape, fed=None) -> float:

@@ -4,9 +4,9 @@
 this is the one place they are turned into the serving headline numbers —
 TTFT, ITL (mean inter-token gap, ``(finish − first_token)/(n_tokens − 1)``,
 undefined for single-token requests), and end-to-end latency, each as
-p50/p95/mean percentiles over a request set.  ``serving_bench.py`` and the
-telemetry summary exporter both consume this instead of re-deriving
-percentiles ad hoc.
+p50/p95/mean percentiles over a request set.  The telemetry summary
+exporter and ``examples/serve_demo.py`` both consume this instead of
+re-deriving percentiles ad hoc.
 """
 from __future__ import annotations
 

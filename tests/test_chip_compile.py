@@ -69,18 +69,9 @@ def _compile(fn, *args):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("kernel", ["fused_axpy", "local_update",
-                                    "server_update"])
-def test_fedadc_update_compiles(one_chip, kernel, dtype):
+def test_fedadc_update_compiles(one_chip, dtype):
     leaf = _sds((ROWS, ops.LANE), dtype, one_chip)
-    fns = {
-        "fused_axpy": lambda x, y: _fu.fused_axpy(x, y, -0.05),
-        "local_update": lambda t, g, m: _fu.local_update_2d(t, g, m, 0.05),
-        "server_update": lambda t, m, d: _fu.server_update_2d(
-            t, m, d, 0.1, 0.05),
-    }
-    n_in = 2 if kernel == "fused_axpy" else 3
-    _compile(fns[kernel], *([leaf] * n_in))
+    _compile(lambda x, y: _fu.fused_axpy(x, y, -0.05), leaf, leaf)
 
 
 def test_fused_axpy_keeps_leaf_layout(one_chip, mosaic):

@@ -6,6 +6,8 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro.configs.base import FedConfig
+from repro.core.strategies import FedADC
 from repro.kernels import fedadc_update as FU
 from repro.kernels import flash_attention as FA
 from repro.kernels import kd_loss as KD
@@ -166,35 +168,25 @@ def test_ssd_kernel_matches_chunked_jnp():
 
 
 # ---------------------------------------------------------------------------
-# fused FedADC updates
+# FedADC updates: the server step and the local step's fused axpy
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("n", [128, 1000, 4097, 65536])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_fused_local_update_sweep(n, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(5), 3)
-    theta = rand(ks[0], (n,), dtype)
-    g = rand(ks[1], (n,), dtype)
-    m = rand(ks[2], (n,), dtype)
-    out = ops.fedadc_local_update({"p": theta}, {"p": g}, {"p": m}, 0.05)
-    expect = ref.fedadc_local_update(theta, g, m, 0.05)
-    tol = 1e-2 if dtype == jnp.bfloat16 else 1e-6
-    np.testing.assert_allclose(np.asarray(out["p"], np.float32),
-                               np.asarray(expect, np.float32), atol=tol)
-
-
 @settings(max_examples=20, deadline=None)
 @given(n=st.integers(1, 3000), eta=st.floats(1e-4, 1.0),
-       gamma=st.floats(-1.0, 1.0))
-def test_property_server_update(n, eta, gamma):
+       beta_global=st.floats(0.0, 1.0), beta_local=st.floats(0.0, 1.0))
+def test_property_server_update(n, eta, beta_global, beta_local):
+    """FedADC's server update (Alg. 3 lines 16-19) is the closed form
+    m' = (β_g − β_l)·m + Δ̄/η, θ' = θ − αη·m'."""
+    fed = FedConfig(strategy="fedadc", eta=eta, beta_global=beta_global,
+                    beta_local=beta_local, alpha=1.5)
     rng = np.random.RandomState(n)
-    theta = jnp.asarray(rng.randn(n).astype(np.float32))
-    m = jnp.asarray(rng.randn(n).astype(np.float32))
-    d = jnp.asarray(rng.randn(n).astype(np.float32))
-    t2, m2 = ops.fedadc_server_update({"p": theta}, {"p": m}, {"p": d},
-                                      gamma, eta)
-    te, me = ref.fedadc_server_update(theta, m, d, gamma, eta)
+    theta, m, d = (rng.randn(n).astype(np.float32) for _ in range(3))
+    t2, s2 = FedADC().server_update({"m": {"p": jnp.asarray(m)}},
+                                    {"p": jnp.asarray(theta)},
+                                    {"p": jnp.asarray(d)}, fed)
+    me = (beta_global - beta_local) * m.astype(np.float64) + d / eta
+    te = theta - 1.5 * eta * me
+    np.testing.assert_allclose(s2["m"]["p"], me, atol=1e-5, rtol=1e-4)
     np.testing.assert_allclose(t2["p"], te, atol=1e-5, rtol=1e-4)
-    np.testing.assert_allclose(m2["p"], me, atol=1e-5, rtol=1e-4)
 
 
 def _bits(x):
@@ -204,7 +196,8 @@ def _bits(x):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("shape", [(3, 256, 384), (3, 128), (2560,), (7, 13),
-                                   (5,), (2, 3, 3, 40)])
+                                   (5,), (2, 3, 3, 40), (128,), (1000,),
+                                   (4097,), (65536,)])
 def test_fused_axpy_pytree_shapes(shape, dtype):
     """The axpy runs on each leaf in its own shape; x + a·y is element by
     element, so it is bitwise the compiled x + a·y (compiled too: the

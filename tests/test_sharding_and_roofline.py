@@ -137,15 +137,16 @@ class TestCollectiveParser:
         st = R.parse_collectives("%x = f32[8]{0} add(%a, %b)")
         assert st.wire_bytes == 0
 
-    def test_dominant_term(self):
+    def test_as_dict_reports_counts_not_seconds(self):
+        st = R.parse_collectives(self.HLO)
         rl = R.Roofline(flops=197e12, bytes_accessed=819e9 * 3,
-                        wire_bytes=50e9, chips=256,
-                        collectives=R.parse_collectives(""),
-                        per_device_hbm=0)
-        assert rl.dominant == "memory"
-        np.testing.assert_allclose(rl.compute_s, 1.0)
-        np.testing.assert_allclose(rl.memory_s, 3.0)
-        np.testing.assert_allclose(rl.collective_s, 1.0)
+                        wire_bytes=st.wire_bytes, chips=256, collectives=st,
+                        per_device_hbm=2 * 2**30)
+        d = rl.as_dict()
+        assert d == {"flops": 197e12, "bytes": 819e9 * 3,
+                     "wire_bytes": st.wire_bytes, "per_device_hbm_gb": 2.0,
+                     "collective_counts": st.counts}
+        assert not [k for k in d if k.endswith("_s")]
 
 
 class TestModelFlops:

@@ -467,6 +467,9 @@ def _pallas_exports(ops_path: str) -> Set[str]:
 KERNEL_ORACLE_ALIASES = {
     "qsgd_compress_leaf": "qsgd_quantize",
     "topk_compress_leaf": "topk_threshold_select",
+    # flash_attention's VJP rules: its oracle's forward and VJP
+    "_flash_attention_fwd": "flash_attention",
+    "_flash_attention_bwd": "flash_attention",
 }
 
 

@@ -25,7 +25,6 @@ from repro.kernels import compress as _cp
 from repro.kernels import fedadc_update as _fu
 from repro.kernels import flash_attention as _fa
 from repro.kernels import kd_loss as _kd
-from repro.kernels import ref as _ref
 from repro.kernels import sparse_reduce as _sr
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import weighted_reduce as _wr
@@ -207,48 +206,71 @@ def sparse_weighted_delta_reduce(values, indices, weights, shape, dtype):
 # ---------------------------------------------------------------------------
 # attention / ssd / kd
 # ---------------------------------------------------------------------------
+def _attention_specs(q, k):
+    """Under a mesh, the model-layout (B,L,H,D) spec of q, k, v and of
+    their gradients, and the (B, H, ...) spec of the kernel-layout
+    residuals: batch over "data", heads over "model" where they divide; q
+    and kv heads split alike, so each shard keeps whole GQA groups."""
+    mesh = _mesh()
+
+    def axis(name, dim):
+        n = 1 if mesh is None else mesh.shape.get(name, 1)
+        return name if n > 1 and dim % n == 0 else None
+    batch, heads = axis("data", q.shape[0]), axis("model", k.shape[2])
+    return P(batch, None, heads, None), P(batch, heads, None, None)
+
+
+def _swap_seq_heads(*ts):
+    """(B,L,H,D) <-> (B,H,L,D) on each of `ts`."""
+    return tuple(jnp.moveaxis(t, 1, 2) for t in ts)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=True, window=0, block_q=None,
                     block_k=None):
     """q (B,L,H,D) model layout -> (B,L,H,D).
 
-    Differentiable: the forward pass is the Pallas kernel, the backward
-    pass is the VJP of the float32 oracle (``ref.flash_attention``),
-    recomputed from (q, k, v) — so a training step through this op keeps
-    no (L, L) residual between its passes."""
+    Differentiable: the forward pass is the Pallas kernel, which under the
+    VJP also keeps each row's log-sum-exp; the backward pass is the Pallas
+    dq and dk/dv kernels (``_fa.flash_attention_bwd``), which recompute
+    the probabilities block by block, so a training step through this op
+    holds no (L, L) array."""
     def kernel(q, k, v):
         out = _fa.flash_attention(
-            jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2),
-            jnp.moveaxis(v, 1, 2), causal=causal, window=window,
+            *_swap_seq_heads(q, k, v), causal=causal, window=window,
             block_q=block_q, block_k=block_k, interpret=_interpret())
         return jnp.moveaxis(out, 1, 2)
 
-    spec = P()
-    mesh = _mesh()
-    if mesh is not None:
-        # batch over "data", heads over "model" where they divide; q and kv
-        # heads split alike, so each shard keeps whole GQA groups
-        def axis(name, dim):
-            n = mesh.shape.get(name, 1)
-            return name if n > 1 and dim % n == 0 else None
-        spec = P(axis("data", q.shape[0]), None, axis("model", k.shape[2]),
-                 None)
+    spec, _ = _attention_specs(q, k)
     return _on_shards(kernel, (spec,) * 3, spec, q, k, v)
 
 
 def _flash_attention_fwd(q, k, v, causal, window, block_q, block_k):
-    return flash_attention(q, k, v, causal, window, block_q, block_k), \
-        (q, k, v)
+    def kernel(q, k, v):
+        o, lse = _fa.flash_attention(
+            *_swap_seq_heads(q, k, v), causal=causal, window=window,
+            block_q=block_q, block_k=block_k, interpret=_interpret(),
+            residuals=True)
+        return jnp.moveaxis(o, 1, 2), o, lse
+
+    spec, rspec = _attention_specs(q, k)
+    out, o, lse = _on_shards(kernel, (spec,) * 3, (spec, rspec, rspec), q, k,
+                             v)
+    return out, (q, k, v, o, lse)
 
 
 def _flash_attention_bwd(causal, window, block_q, block_k, res, g):
-    def oracle(q, k, v):
-        out = _ref.flash_attention(jnp.moveaxis(q, 1, 2),
-                                   jnp.moveaxis(k, 1, 2),
-                                   jnp.moveaxis(v, 1, 2),
-                                   causal=causal, window=window)
-        return jnp.moveaxis(out, 1, 2)
-    return jax.vjp(oracle, *res)[1](g)
+    def kernel(q, k, v, o, lse, g):
+        q, k, v, g = _swap_seq_heads(q, k, v, g)
+        grads = _fa.flash_attention_bwd(
+            q, k, v, o, lse, g, causal=causal, window=window,
+            block_q=block_q, block_k=block_k, interpret=_interpret())
+        return _swap_seq_heads(*grads)
+
+    q, k = res[:2]
+    spec, rspec = _attention_specs(q, k)
+    return _on_shards(kernel, (spec,) * 3 + (rspec, rspec, spec), (spec,) * 3,
+                      *res, g)
 
 
 flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)

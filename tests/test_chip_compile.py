@@ -15,6 +15,7 @@ this file.
 import math
 import os
 import re
+import sys
 import types
 from pathlib import Path
 
@@ -129,7 +130,7 @@ def _fwd_bwd(q, k, v, g):
 
 def test_flash_attention_backward_compiles(one_chip, mosaic):
     """The custom VJP as a training step runs it: the kernel forward and
-    the float32-oracle backward in one program."""
+    the Pallas dq and dk/dv kernels of the backward in one program."""
     q, k, v = _qkv(one_chip)
     compiled = _compile(_fwd_bwd, q, k, v, q)
     out, grads = compiled.out_info
@@ -157,13 +158,23 @@ def _cell_qkv(one_chip, cell):
 
 @pytest.mark.parametrize("cell", CELL_ATTENTION)
 def test_flash_attention_cell_shapes_compile(one_chip, mosaic, cell):
-    """At each cell's shapes the kernel's own block choice fits the
-    chip's VMEM, forward and under the VJP a training step takes."""
+    """At each cell's shapes the kernels' own block choices fit the
+    chip's VMEM, forward and under the VJP a training step takes.  The
+    training step's attention is the three kernels and nothing of the
+    dense oracle: no float32 (…, L, L) array is left in its program."""
     q, k, v = _cell_qkv(one_chip, cell)
     _compile(lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
              q, k, v)
-    out, grads = _compile(_fwd_bwd, q, k, v, q).out_info
+    compiled = _compile(_fwd_bwd, q, k, v, q)
+    out, grads = compiled.out_info
     assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+    hlo = compiled.as_text()
+    sys.path.insert(0, str(CHIP_DIR))
+    from bench.trace import hlo_kernels
+    assert sorted(k["kernel"] for k in hlo_kernels(hlo).values()) == [
+        "_flash_dkv_kernel", "_flash_dq_kernel", "_flash_kernel"]
+    L = q.shape[1]
+    assert not re.search(rf"f32\[(?:\d+,)*{L},{L}\]", hlo)
 
 
 @pytest.fixture
@@ -197,6 +208,34 @@ def test_flash_attention_meets_its_reader(one_chip, mosaic, bench, cell):
     ns = round(least * 1e9)
     tr = {"window": [0, 2 * ns], "host": [], "kernels": kernels,
           "devices": {0: {"ops": [[op, 0, ns]], "async": []}}}
+    ctx = types.SimpleNamespace(trace=tr, peak=peak, flops=lambda _: fl)
+    assert reader.read(ctx) == pytest.approx(100.0, rel=1e-5)
+
+
+@pytest.mark.parametrize("cell", CELL_ATTENTION)
+def test_flash_attention_bwd_meets_its_reader(one_chip, mosaic, bench, cell):
+    """`flash_attention_bwd_roofline` finds the backward's calls by the
+    names ``_flash_dkv_kernel`` and ``_flash_dq_kernel``, and counts one
+    backward a dkv call from its first two operands, which it takes to
+    be q (B·H, L, D) and k (B·Hk, L, D).  A call pair that lasts its
+    least time at the true shapes must read 100%."""
+    trace, _, _, peak = bench
+    from bench.registry import Registry
+    registry = Registry(CHIP_DIR)
+    fl = registry.flops("flash_attention_bwd")
+    reader = registry.metric("flash_attention_bwd_roofline")
+    B, L, H, Hk = CELL_ATTENTION[cell]
+    q, k, v = _cell_qkv(one_chip, cell)
+    kernels = trace.hlo_kernels(_compile(_fwd_bwd, q, k, v, q).as_text())
+    op = {info["kernel"]: name for name, info in kernels.items()}
+    assert kernels[op["_flash_dkv_kernel"]]["operands"][:2] == [
+        ["f32", [B * H, L, 128]], ["f32", [B * Hk, L, 128]]]
+    least = fl.least_time_s(B * H, B * Hk, L, 128, 4, peak)
+    ns = round(least * 1e9)
+    ops_ = [[op["_flash_dkv_kernel"], 0, ns // 3],
+            [op["_flash_dq_kernel"], ns // 3, ns]]
+    tr = {"window": [0, 2 * ns], "host": [], "kernels": kernels,
+          "devices": {0: {"ops": ops_, "async": []}}}
     ctx = types.SimpleNamespace(trace=tr, peak=peak, flops=lambda _: fl)
     assert reader.read(ctx) == pytest.approx(100.0, rel=1e-5)
 

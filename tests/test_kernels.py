@@ -99,6 +99,74 @@ def test_flash_band_is_the_visible_blocks(L, block_q, block_k, causal,
         assert fetched == sorted(fetched)   # no block fetched twice
 
 
+@pytest.mark.parametrize("L,block_q,block_k,causal,window", [
+    (1024, 256, 512, True, 0),
+    (1024, 128, 128, True, 0),
+    (1024, 512, 256, True, 0),
+    (192, 128, 64, True, 0),     # ragged last q block
+    (256, 64, 64, True, 32),
+    (256, 64, 128, True, 100),
+    (512, 128, 128, False, 0),
+])
+def test_flash_q_band_is_the_visible_blocks(L, block_q, block_k, causal,
+                                            window):
+    """The backward's mirror of ``_band``: a k block computes with, and
+    fetches q, dO and statistics for, exactly the q blocks that hold a
+    (query, key) pair the mask lets through; every other step keeps the
+    index of a visible block, so the pipeline fetches nothing new."""
+    n_qb = -(-L // block_q)
+    for ki in range(-(-L // block_k)):
+        lo, hi = FA._q_band(ki, block_q=block_q, block_k=block_k, n_qb=n_qb,
+                            causal=causal, window=window)
+        kpos = np.arange(ki * block_k, min((ki + 1) * block_k, L))[:, None]
+        qpos = np.arange(L)[None, :]
+        seen = np.ones((len(kpos), L), bool)
+        if causal:
+            seen &= kpos <= qpos
+        if window > 0:
+            seen &= kpos > qpos - window
+        visible = sorted({int(c) // block_q for c in np.nonzero(seen)[1]})
+        assert visible == list(range(int(lo), int(hi) + 1))
+        fetched = [int(np.clip(qi, lo, hi)) for qi in range(n_qb)]
+        assert sorted(set(fetched)) == visible
+        assert fetched == sorted(fetched)   # no block fetched twice
+
+
+@pytest.mark.parametrize("B,H,Hk,L,D,window,block_q,block_k", [
+    (1, 2, 2, 256, 64, 0, 128, 128),    # MHA, exact blocks
+    (1, 4, 1, 256, 64, 0, 128, 256),    # GQA group 4, one k block
+    (2, 8, 2, 256, 64, 0, None, None),  # group 4, the chosen blocks
+    (1, 5, 1, 384, 64, 0, 128, 256),    # group 5, ragged last k block
+    (1, 5, 1, 192, 64, 0, 128, 128),    # group 5, ragged q and k blocks
+    (1, 4, 4, 256, 64, 32, 64, 64),     # sliding window
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_backward_kernels(B, H, Hk, L, D, window, block_q,
+                                          block_k, dtype):
+    """dq from ``_flash_dq_kernel`` and dk, dv from ``_flash_dkv_kernel``,
+    given the forward's log-sum-exp, against the VJP of the oracle."""
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = rand(ks[0], (B, H, L, D), dtype)
+    k = rand(ks[1], (B, Hk, L, D), dtype)
+    v = rand(ks[2], (B, Hk, L, D), dtype)
+    do = rand(ks[3], (B, H, L, D), dtype)
+    o, lse = FA.flash_attention(q, k, v, causal=True, window=window,
+                                interpret=True, residuals=True)
+    np.testing.assert_array_equal(o, FA.flash_attention(
+        q, k, v, causal=True, window=window, interpret=True))
+    got = FA.flash_attention_bwd(q, k, v, o, lse, do, causal=True,
+                                 window=window, block_q=block_q,
+                                 block_k=block_k, interpret=True)
+    _, pullback = jax.vjp(lambda *a: ref.flash_attention(
+        *a, causal=True, window=window), q, k, v)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    for a, b in zip(got, pullback(do)):
+        assert a.shape == b.shape and a.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=tol, rtol=tol)
+
+
 @pytest.mark.parametrize("window", [32, 64, 128])
 def test_flash_attention_window(window):
     ks = jax.random.split(jax.random.PRNGKey(1), 3)

@@ -98,8 +98,11 @@ def test_mixed_precision_round_preserves_master_dtype():
 
 @pytest.mark.parametrize("window", [0, 64])
 def test_flash_attention_gradients_match_oracle(window):
-    """The kernel's custom VJP is the float32 oracle's: gradients through
-    ops.flash_attention equal those through ref.flash_attention."""
+    """The custom VJP's Pallas backward (the dq and dk/dv kernels) gives
+    the float32 oracle's gradients: dq, dk and dv through
+    ops.flash_attention equal those through ref.flash_attention, up to
+    the summation order of a blocked backward (the forward test's fp32
+    tolerance)."""
     from repro.kernels import ops, ref
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     q = jax.random.normal(ks[0], (2, 256, 4, 64))
@@ -118,8 +121,8 @@ def test_flash_attention_gradients_match_oracle(window):
     got = jax.grad(kernel, (0, 1, 2))(q, k, v)
     exp = jax.grad(oracle, (0, 1, 2))(q, k, v)
     for a, b in zip(got, exp):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
-                                   atol=1e-6)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=2e-5)
 
 
 def test_model_gradients_with_kernels_match_jnp():
